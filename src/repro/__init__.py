@@ -1,9 +1,1 @@
-"""NeedleTail-JAX: LIMIT-query engine reproduction (density maps + any-k).
-
-Importing the package installs the JAX version-compat shims (see
-:mod:`repro.compat`) so every entry point — tests, benchmarks, subprocess
-demos — sees a uniform API surface regardless of the installed JAX.
-"""
-from repro import compat as _compat
-
-_compat.install()
+"""NeedleTail-JAX: LIMIT-query engine reproduction (density maps + any-k)."""

@@ -77,7 +77,7 @@ from repro.core.two_prong import two_prong_select_batch
 
 # repro.kernels.plan_wave is imported lazily inside the device-pipeline
 # functions: pulling it here would make every host-only any_k_batch call pay
-# the Pallas import (see repro.compat's import-cost note).
+# the Pallas import.
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.engine import NeedleTailEngine, QueryResult

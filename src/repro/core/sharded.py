@@ -51,7 +51,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 
 class ShardedThresholdResult(NamedTuple):

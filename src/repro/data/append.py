@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.density_map import DensityMapIndex
-from repro.data.block_store import BlockStore, Table
+from repro.data.block_store import BlockStore, Table, blocked_layout
 
 
 def dirtied_block_ids(store: BlockStore, num_new: int) -> np.ndarray:
@@ -49,12 +49,9 @@ def rebuild_store(
     """
     rpb = store.records_per_block
     n = dims_flat.shape[0]
-    lam_new = -(-n // rpb)
-    r, s_ = dims_flat.shape[1], meas_flat.shape[1]
-    pad = lam_new * rpb - n
-    dims_b = np.concatenate([dims_flat, np.full((pad, r), -1, np.int32)]).reshape(lam_new, rpb, r)
-    meas_b = np.concatenate([meas_flat, np.zeros((pad, s_), np.float32)]).reshape(lam_new, rpb, s_)
-    valid_b = np.concatenate([np.ones(n, bool), np.zeros(pad, bool)]).reshape(lam_new, rpb)
+    r = dims_flat.shape[1]
+    dims_b, meas_b, valid_b = blocked_layout(dims_flat, meas_flat, rpb)
+    lam_new = dims_b.shape[0]
 
     # density columns: reuse untouched prefix, recompute only touched blocks
     idx = store.index
@@ -82,9 +79,9 @@ def rebuild_store(
         num_records=n,
     )
     rebuilt = BlockStore(
-        dims=jnp.asarray(dims_b),
-        measures=jnp.asarray(meas_b),
-        valid_rows=jnp.asarray(valid_b),
+        dims=dims_b,
+        measures=meas_b,
+        valid_rows=valid_b,
         index=new_index,
         records_per_block=rpb,
         num_records=n,
@@ -104,11 +101,11 @@ def append_records(store: BlockStore, new: Table) -> BlockStore:
     """
     old_n = store.num_records
     dims_flat = np.concatenate([
-        np.asarray(store.dims).reshape(-1, store.dims.shape[-1])[:old_n],
+        store.dims.reshape(-1, store.dims.shape[-1])[:old_n],
         new.dims.astype(np.int32),
     ])
     meas_flat = np.concatenate([
-        np.asarray(store.measures).reshape(-1, store.measures.shape[-1])[:old_n],
+        store.measures.reshape(-1, store.measures.shape[-1])[:old_n],
         new.measures.astype(np.float32),
     ])
     touched = dirtied_block_ids(store, new.num_records)

@@ -2,8 +2,9 @@
 
 A :class:`Table` is the logical star-schema table (dimension attributes +
 measures).  A :class:`BlockStore` is its physical layout: fixed-size blocks of
-``records_per_block`` rows, stored as dense ``[λ, R, ·]`` tensors so one block is
-one VMEM-tileable slab — the TPU analogue of the paper's 256 KB disk block.
+``records_per_block`` rows — the TPU analogue of the paper's 256 KB disk block.
+The host keeps them as row-major ``[λ, R, ·]`` numpy slabs; the device copy is
+lane-dense ``[λ, ·, R]`` (see :class:`BlockStore`).
 
 Fetches go through :meth:`BlockStore.fetch`, which returns the block slab plus a
 validity mask; the engine charges I/O for fetched blocks through the cost model.
@@ -11,6 +12,7 @@ validity mask; the engine charges I/O for fetched blocks through the cost model.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 from typing import Sequence
 
@@ -39,11 +41,22 @@ class Table:
 
 @dataclasses.dataclass
 class BlockStore:
-    """Physical blocked layout + the DensityMap index built at load time."""
+    """Physical blocked layout + the DensityMap index built at load time.
 
-    dims: jax.Array  # [lam, R, r] int32, padded with -1 (matches no value)
-    measures: jax.Array  # [lam, R, s] f32, padded with 0
-    valid_rows: jax.Array  # [lam, R] bool, False on padding
+    ``dims`` / ``measures`` / ``valid_rows`` are the host slabs that
+    :meth:`fetch` serves.  Construction uploads a device copy in a
+    lane-dense layout, the record axis R minor: ``dims_dev [λ, r, R]`` and
+    ``meas_dev [λ, s, R]``.  TPU memory tiles the two minor dims by
+    (8, 128), so a row-major ``[λ, R, r]`` tensor would pad r = 8 to 128
+    lanes (16x: 51 GB for the paper's 100M-record table); with R minor the
+    copy costs its logical size.  Row validity is not stored on the device:
+    the valid rows are exactly the first ``num_records`` of the flattened
+    layout, so :meth:`gather_device` derives the mask from the block ids.
+    """
+
+    dims: np.ndarray  # [lam, R, r] int32, padded with -1 (matches no value)
+    measures: np.ndarray  # [lam, R, s] f32, padded with 0
+    valid_rows: np.ndarray  # [lam, R] bool, False on padding
     index: DensityMapIndex
     records_per_block: int
     num_records: int
@@ -53,11 +66,10 @@ class BlockStore:
         return int(self.dims.shape[0])
 
     def __post_init__(self):
-        # host mirrors for the CPU-side engine: eager jnp gathers would compile
-        # one executable per distinct block-count shape (~250 ms each)
-        self._dims_np = np.asarray(self.dims)
-        self._meas_np = np.asarray(self.measures)
-        self._valid_np = np.asarray(self.valid_rows)
+        self.dims_dev = jnp.asarray(np.ascontiguousarray(self.dims.transpose(0, 2, 1)))
+        self.meas_dev = jnp.asarray(
+            np.ascontiguousarray(self.measures.transpose(0, 2, 1))
+        )
         # callbacks fired with the dirtied block ids when the write path
         # (repro.data.append) rewrites blocks of this store's lineage
         self._invalidation_listeners: list = []
@@ -99,48 +111,45 @@ class BlockStore:
     def fetch(self, block_ids: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Gather block slabs: (dims [B,R,r], measures [B,R,s], row_valid [B,R])."""
         ids = np.asarray(block_ids, dtype=np.int64)
-        return self._dims_np[ids], self._meas_np[ids], self._valid_np[ids]
+        return self.dims[ids], self.measures[ids], self.valid_rows[ids]
 
-    def fetch_device(
-        self, block_ids, interpret: bool | None = None
-    ) -> tuple[jax.Array, jax.Array, jax.Array]:
-        """Device-resident union fetch: gather a wave's deduplicated block
-        union from the device-resident ``[λ, R, ·]`` slabs in one launch per
-        tensor via the :func:`repro.kernels.plan_wave.block_gather` Pallas
-        kernel (scalar-prefetched ids drive the gather ``index_map``).
+    def gather_device(self, block_ids) -> tuple[jax.Array, jax.Array, jax.Array, int]:
+        """Lane-dense device union gather: one jitted launch of the
+        :func:`repro.kernels.plan_wave.block_gather` Pallas kernel per tensor
+        (scalar-prefetched ids drive the gather ``index_map``).
+
+        Returns ``(dims [Ub, r, R], meas [Ub, s, R], valid [Ub, R], U)``.
+        The ids are padded to ``Ub``, the next power of two ≥ U, so a stream
+        of unions of varying size compiles once per bucket, not once per
+        size; rows ``U:`` repeat block 0 and carry no meaning.  Row ``i < U``
+        holds block ``block_ids[i]``, each record a column, byte-identical
+        to ``fetch(block_ids)[·][i]`` transposed.
+        """
+        ids = np.asarray(block_ids, dtype=np.int32).ravel()
+        u = int(ids.size)
+        ub = 1 << max(u - 1, 0).bit_length()
+        padded = np.zeros((ub,), np.int32)
+        padded[:u] = ids
+        dd, dm, dv = _gather_lane_dense(
+            self.dims_dev, self.meas_dev, jnp.asarray(padded),
+            jnp.int32(self.num_records),
+            interpret=jax.default_backend() != "tpu",
+        )
+        return dd, dm, dv, u
+
+    def fetch_device(self, block_ids) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Device-resident union fetch: :meth:`gather_device` transposed back
+        to the row-major ``(dims [U,R,r], measures [U,R,s], valid [U,R])``
+        that :meth:`fetch` returns, byte for byte.
 
         The device-side counterpart of :meth:`fetch` for consumers that keep
-        the slabs on device (e.g. exemplar measures feeding an LM): no host
-        mirror is materialized, so it adds zero device→host transfers to the
-        wave pipeline.  Values are byte-identical to :meth:`fetch`.  This is
-        also the HBM tier's fill path in the tiered storage hierarchy: a
-        :class:`repro.storage.tiers.TierStack` with ``device_fill`` enabled
-        admits backing-store misses into its device tier through one union
-        gather here, and device consumers read that residency back without
-        any transfer via :meth:`repro.storage.tiers.TierStack.get_device`.
-
-        Parameters
-        ----------
-        block_ids : array-like
-            Deduplicated block ids (``[U]``).
-        interpret : bool | None
-            Force Pallas interpret mode; ``None`` auto-selects (interpret
-            everywhere but TPU, matching ``repro.kernels.ops``).
+        the slabs on device: no host mirror is materialized, so it adds zero
+        device→host transfers.  The tiered storage hierarchy fills its HBM
+        tier through :meth:`gather_device` (lane-dense slabs), and device
+        consumers read that residency back through
+        :meth:`repro.storage.tiers.TierStack.get_device`.
         """
-        from repro.kernels.plan_wave import block_gather
-
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        # jnp.asarray alone: lists/numpy upload, device-resident ids stay on
-        # device (np.asarray here would force a device→host round-trip and
-        # trip the transfer-guard probe)
-        ids = jnp.asarray(block_ids, jnp.int32)
-        return (
-            block_gather(self.dims, ids, interpret=interpret),
-            block_gather(self.measures, ids, interpret=interpret),
-            block_gather(self.valid_rows.astype(jnp.int8), ids, interpret=interpret)
-            != 0,
-        )
+        return _row_major(*self.gather_device(block_ids))
 
     def predicate_mask(
         self, block_dims, predicates: Sequence[tuple[int, int]], op: str = AND
@@ -156,26 +165,60 @@ class BlockStore:
         return int(self.dims.size * 4 + self.measures.size * 4)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gather_lane_dense(dims_dev, meas_dev, ids, num_records, interpret: bool):
+    from repro.kernels.plan_wave import block_gather
+
+    rpb = dims_dev.shape[-1]
+    row = ids[:, None] * rpb + jnp.arange(rpb, dtype=jnp.int32)[None, :]
+    return (
+        block_gather(dims_dev, ids, interpret=interpret),
+        block_gather(meas_dev, ids, interpret=interpret),
+        row < num_records,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _row_major(dd, dm, dv, u: int):
+    return dd[:u].transpose(0, 2, 1), dm[:u].transpose(0, 2, 1), dv[:u]
+
+
+def blocked_layout(
+    dims: np.ndarray, measures: np.ndarray, records_per_block: int
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Flat ``[N, r]`` / ``[N, s]`` rows -> the host slabs of a
+    :class:`BlockStore`: ``(dims [λ, R, r] int32 padded with -1,
+    measures [λ, R, s] f32 padded with 0, valid [λ, R] bool)``.
+
+    Each output is allocated once at its padded size and filled in place
+    (one host copy of the table, whatever the input dtype).
+    """
+    n = dims.shape[0]
+    rpb = records_per_block
+    lam = -(-n // rpb)
+
+    def blocked(a: np.ndarray, fill, dtype) -> np.ndarray:
+        out = np.full((lam * rpb,) + a.shape[1:], fill, dtype)
+        out[:n] = a
+        return out.reshape((lam, rpb) + a.shape[1:])
+
+    valid = np.zeros((lam * rpb,), bool)
+    valid[:n] = True
+    return (
+        blocked(dims, -1, np.int32),
+        blocked(measures, 0.0, np.float32),
+        valid.reshape(lam, rpb),
+    )
+
+
 def build_block_store(table: Table, records_per_block: int) -> BlockStore:
-    n, r = table.dims.shape
-    s = table.measures.shape[1]
-    lam = -(-n // records_per_block)
-    pad = lam * records_per_block - n
-    dims = np.concatenate(
-        [table.dims, np.full((pad, r), -1, dtype=table.dims.dtype)]
-    ).reshape(lam, records_per_block, r)
-    meas = np.concatenate(
-        [table.measures, np.zeros((pad, s), dtype=table.measures.dtype)]
-    ).reshape(lam, records_per_block, s)
-    valid = np.concatenate(
-        [np.ones(n, dtype=bool), np.zeros(pad, dtype=bool)]
-    ).reshape(lam, records_per_block)
     index = build_density_maps(table.dims, table.cards, records_per_block)
+    dims, meas, valid = blocked_layout(table.dims, table.measures, records_per_block)
     return BlockStore(
-        dims=jnp.asarray(dims.astype(np.int32)),
-        measures=jnp.asarray(meas.astype(np.float32)),
-        valid_rows=jnp.asarray(valid),
+        dims=dims,
+        measures=meas,
+        valid_rows=valid,
         index=index,
         records_per_block=records_per_block,
-        num_records=n,
+        num_records=table.num_records,
     )

@@ -5,13 +5,4 @@ batched forms), window_scan (prefix sums for TWO-PRONG), theta_stats
 (θ-bisection THRESHOLD).  Framework kernels: flash_attention, ssd_chunk
 (Mamba2).  Public API in :mod:`repro.kernels.ops`; jnp oracles in
 :mod:`repro.kernels.ref`.
-
-``CompilerParams`` is resolved once here so every kernel module compiles
-against whichever name the installed JAX exports (older JAX calls it
-``TPUCompilerParams``).
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-
-__all__ = ["CompilerParams"]

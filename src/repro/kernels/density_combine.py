@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 LANE_TILE = 512  # λ-tile; multiple of the 128-lane VPU width
 
@@ -44,7 +43,7 @@ def _kernel(rows_ref, dens_ref, out_ref, *, op: str, gamma: int):
     def _init():
         out_ref[...] = jnp.full_like(out_ref, 1.0 if op == "and" else 0.0)
 
-    tile = dens_ref[0, :]
+    tile = dens_ref[0]  # [1, LANE_TILE]
     if op == "and":
         out_ref[...] *= tile
     else:
@@ -57,6 +56,20 @@ def _kernel(rows_ref, dens_ref, out_ref, *, op: str, gamma: int):
             out_ref[...] = jnp.minimum(out_ref[...], 1.0)
 
 
+def _unit_rows(densities: jax.Array, lam_p: int) -> jax.Array:
+    """``[rows, λ]`` -> ``[rows, 1, λ_p]`` (zero-padded to the lane tile).
+
+    A gathered row gets its own unit axis so each block's last two dims are
+    ``(1, LANE_TILE)``: the TPU lowering accepts a second-minor block dim of
+    1 only when it equals the array's, which a ``(1, LANE_TILE)`` block over
+    the 2-D ``[rows, λ]`` tensor does not.
+    """
+    rows, lam = densities.shape
+    if lam_p != lam:
+        densities = jnp.pad(densities, ((0, 0), (0, lam_p - lam)))
+    return densities.reshape(rows, 1, lam_p)
+
+
 def density_combine(
     densities: jax.Array,  # [rows, lam] f32
     row_ids: jax.Array,  # [gamma] int32
@@ -66,10 +79,7 @@ def density_combine(
     """Returns the combined per-block density vector ``[lam]``."""
     rows, lam = densities.shape
     gamma = row_ids.shape[0]
-    pad = (-lam) % LANE_TILE
-    if pad:
-        densities = jnp.pad(densities, ((0, 0), (0, pad)))
-    lam_p = lam + pad
+    lam_p = lam + (-lam) % LANE_TILE
     grid = (lam_p // LANE_TILE, gamma)
 
     out = pl.pallas_call(
@@ -79,18 +89,18 @@ def density_combine(
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (1, LANE_TILE), lambda i, j, rows: (rows[j], i)
+                    (1, 1, LANE_TILE), lambda i, j, rows: (rows[j], 0, i)
                 ),
             ],
-            out_specs=pl.BlockSpec((LANE_TILE,), lambda i, j, rows: (i,)),
+            out_specs=pl.BlockSpec((1, LANE_TILE), lambda i, j, rows: (0, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((lam_p,), densities.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, lam_p), densities.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
-    )(row_ids.astype(jnp.int32), densities)
-    return out[:lam]
+    )(row_ids.astype(jnp.int32), _unit_rows(densities, lam_p))
+    return out[0, :lam]
 
 
 def _batch_kernel(rows_ref, dens_ref, out_ref, *, op: str, gamma: int):
@@ -101,7 +111,7 @@ def _batch_kernel(rows_ref, dens_ref, out_ref, *, op: str, gamma: int):
     def _init():
         out_ref[...] = jnp.full_like(out_ref, 1.0 if op == "and" else 0.0)
 
-    tile = dens_ref[0, :]
+    tile = dens_ref[...]  # [1, 1, LANE_TILE]
     # padded row slots (-1) contribute the ⊕-identity; the index_map clamped
     # their gather to row 0, so mask the loaded tile out here
     valid = rows_ref[q, j] >= 0
@@ -133,10 +143,7 @@ def density_combine_batch(
     """
     rows, lam = densities.shape
     nq, gamma = row_matrix.shape
-    pad = (-lam) % LANE_TILE
-    if pad:
-        densities = jnp.pad(densities, ((0, 0), (0, pad)))
-    lam_p = lam + pad
+    lam_p = lam + (-lam) % LANE_TILE
     grid = (nq, lam_p // LANE_TILE, gamma)
 
     out = pl.pallas_call(
@@ -146,19 +153,21 @@ def density_combine_batch(
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (1, LANE_TILE),
-                    lambda q, i, j, rows: (jnp.maximum(rows[q, j], 0), i),
+                    (1, 1, LANE_TILE),
+                    lambda q, i, j, rows: (jnp.maximum(rows[q, j], 0), 0, i),
                 ),
             ],
-            out_specs=pl.BlockSpec((1, LANE_TILE), lambda q, i, j, rows: (q, i)),
+            out_specs=pl.BlockSpec(
+                (1, 1, LANE_TILE), lambda q, i, j, rows: (q, 0, i)
+            ),
         ),
-        out_shape=jax.ShapeDtypeStruct((nq, lam_p), densities.dtype),
+        out_shape=jax.ShapeDtypeStruct((nq, 1, lam_p), densities.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
-    )(row_matrix.astype(jnp.int32), densities)
-    return out[:, :lam]
+    )(row_matrix.astype(jnp.int32), _unit_rows(densities, lam_p))
+    return out[:, 0, :lam]
 
 
 def _combine_local(dens_local: jax.Array, row_matrix: jax.Array, op: str) -> jax.Array:
@@ -215,7 +224,7 @@ def density_combine_batch_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def body(dens_local: jax.Array, rm: jax.Array) -> jax.Array:
         if use_kernel:

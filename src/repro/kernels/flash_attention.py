@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 TILE_Q = 128
 TILE_KV = 128
@@ -124,7 +123,7 @@ def flash_attention(
             pltpu.VMEM((TILE_Q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
     )(q, k, v)

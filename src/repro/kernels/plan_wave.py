@@ -30,7 +30,7 @@ the host's per-query algo choice onto the device-resident exclusion mask, so
 the next round plans against up-to-date exclusions without re-uploading them.
 
 :func:`block_gather` materializes the deduplicated block union of a wave from
-the device-resident ``[λ, R, ·]`` store slabs in one gather launch — the
+the device-resident lane-dense ``[λ, ·, R]`` store slabs in one gather launch — the
 scalar-prefetched block ids drive the input ``index_map`` exactly like the
 predicate-row gather in :mod:`repro.kernels.density_combine`.
 
@@ -39,7 +39,6 @@ Pure-jnp oracles live in :mod:`repro.kernels.ref` (``plan_wave_ref``,
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
@@ -50,7 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.threshold import threshold_sort_batch
 from repro.core.two_prong import two_prong_select_batch
-from repro.kernels import CompilerParams
 from repro.kernels.density_combine import _combine_local, density_combine_batch
 
 THETA_FANOUT = 8  # θ-stats candidate count (kernel wants a multiple of 8)
@@ -302,25 +300,28 @@ def _gather_kernel(ids_ref, src_ref, out_ref):
 
 
 def block_gather(
-    slab: jax.Array,  # [λ, R, d] (or [λ, R]) block-major store tensor
-    block_ids: jax.Array,  # [U] int32 deduplicated union ids
+    slab: jax.Array,  # [λ, d, R] (or [λ, R]) block-major, lane-dense store tensor
+    block_ids: jax.Array,  # [U] int32 union ids
     interpret: bool = False,
 ) -> jax.Array:
-    """Gather ``slab[block_ids]`` in one Pallas launch: ``[U, R, d]``.
+    """Gather ``slab[block_ids]`` in one Pallas launch: ``[U, d, R]``.
 
     The scalar-prefetched ids drive the input ``index_map``, so each union
     block streams HBM→VMEM exactly once and the gather itself costs nothing —
     the device-resident form of the §4.1 "fetch every planned block once"
-    union fetch.  Oracle: :func:`repro.kernels.ref.block_gather_ref`.
+    union fetch.  The record axis R is minor (the store's lane-dense layout),
+    so a block's ``(d, R)`` tile is the array's own last two dims, which the
+    TPU lowering accepts for any d; a 2-D ``[λ, R]`` slab travels as
+    ``[λ, 1, R]``.  Oracle: :func:`repro.kernels.ref.block_gather_ref`.
     """
     squeeze = slab.ndim == 2
     if squeeze:
-        slab = slab[:, :, None]
-    lam, r, d = slab.shape
+        slab = slab[:, None, :]
+    lam, d, r = slab.shape
     u = block_ids.shape[0]
     if u == 0 or lam == 0:
-        out = jnp.zeros((u, r, d), slab.dtype)
-        return out[:, :, 0] if squeeze else out
+        out = jnp.zeros((u, d, r), slab.dtype)
+        return out[:, 0, :] if squeeze else out
 
     out = pl.pallas_call(
         _gather_kernel,
@@ -328,23 +329,13 @@ def block_gather(
             num_scalar_prefetch=1,
             grid=(u,),
             in_specs=[
-                pl.BlockSpec((1, r, d), lambda i, ids: (ids[i], 0, 0)),
+                pl.BlockSpec((1, d, r), lambda i, ids: (ids[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, r, d), lambda i, ids: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, d, r), lambda i, ids: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((u, r, d), slab.dtype),
+        out_shape=jax.ShapeDtypeStruct((u, d, r), slab.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(block_ids.astype(jnp.int32), slab)
-    return out[:, :, 0] if squeeze else out
+    return out[:, 0, :] if squeeze else out
 
-
-#: jit entry point for the single-shot fused planner (static plan geometry).
-plan_wave_jit = jax.jit(
-    plan_wave, static_argnames=("records_per_block", "op", "use_kernel", "interpret")
-)
-
-#: jit entry point for the union gather (static interpret flag).
-block_gather_jit = jax.jit(
-    functools.partial(block_gather), static_argnames=("interpret",)
-)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 CHUNK = 128
 
@@ -88,7 +87,7 @@ def ssd_scan(
         out_shape=jax.ShapeDtypeStruct((b, h, s, dh), u.dtype),
         scratch_shapes=[pltpu.VMEM((ds, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(u, ldecay, bmat, cmat)

@@ -21,7 +21,7 @@ form.  This is the kernel the §Perf hillclimb of the paper-technique cell tunes
 ``Q·2·T`` floats then merges all shards for the whole wave.
 
 Grid: ``(λ_tiles,)`` scalar / ``(Q, λ_tiles)`` batched, outputs accumulated
-across λ steps (the ``[T]`` / ``[1, T]`` output blocks are revisited every step;
+across λ steps (the ``[T]`` / ``[1, T, 1]`` output blocks are revisited every step;
 the query axis is outermost and parallel-safe, mirroring
 :func:`repro.kernels.density_combine.density_combine_batch`).
 """
@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 TILE = 2048
 
@@ -78,7 +77,7 @@ def theta_stats(
             jax.ShapeDtypeStruct((T,), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(combined, thetas)
     return counts, recsum
 
@@ -91,11 +90,11 @@ def _batch_kernel(x_ref, thetas_ref, counts_ref, recsum_ref):
         counts_ref[...] = jnp.zeros_like(counts_ref)
         recsum_ref[...] = jnp.zeros_like(recsum_ref)
 
-    x = x_ref[0, :]  # [TILE] this query's λ-tile
-    th = thetas_ref[0, :]  # [T] this query's candidate thresholds
-    m = x[None, :] >= th[:, None]  # [T, TILE]
-    counts_ref[...] += jnp.sum(m, axis=1).astype(jnp.float32)[None, :]
-    recsum_ref[...] += jnp.sum(jnp.where(m, x[None, :], 0.0), axis=1)[None, :]
+    x = x_ref[0]  # [1, TILE] this query's λ-tile
+    th = thetas_ref[0]  # [T, 1] this query's candidate thresholds
+    m = x >= th  # [T, TILE]
+    counts_ref[...] += jnp.sum(m, axis=1, keepdims=True).astype(jnp.float32)[None]
+    recsum_ref[...] += jnp.sum(jnp.where(m, x, 0.0), axis=1, keepdims=True)[None]
 
 
 def theta_stats_batch(
@@ -110,8 +109,8 @@ def theta_stats_batch(
     combined : jax.Array
         ``[Q, λ]`` float32 ⊕-combined density rows, one per wave query.
     thetas : jax.Array
-        ``[Q, T]`` float32 candidate thresholds (T a multiple of 8); each
-        query bisects its own θ bracket, so rows are independent.
+        ``[Q, T]`` float32 candidate thresholds; each query bisects its own
+        θ bracket, so rows are independent.
     interpret : bool
         Run the Pallas kernel in interpret mode (CPU tests).
 
@@ -121,6 +120,13 @@ def theta_stats_batch(
         ``[Q, T]`` each: ``counts[q, t] = #{b : combined[q, b] >= thetas[q, t]}``
         and ``recsum[q, t] = Σ_{b : combined[q, b] >= thetas[q, t]} combined[q, b]``
         — row q bit-identical to ``theta_stats(combined[q], thetas[q])``.
+
+    Notes
+    -----
+    Rows travel as ``[Q, 1, λ]`` and thresholds as ``[Q, T, 1]`` so every
+    block's last two dims equal the array's (the TPU lowering rejects a
+    ``(1, TILE)`` block over a 2-D ``[Q, λ]`` array), and the ``[T, TILE]``
+    comparison needs no in-kernel transpose.
     """
     nq, lam = combined.shape
     _, T = thetas.shape
@@ -129,24 +135,25 @@ def theta_stats_batch(
         combined = jnp.pad(
             combined, ((0, 0), (0, pad)), constant_values=-1.0
         )  # never >= θ>0
+    lam_p = lam + pad
     counts, recsum = pl.pallas_call(
         _batch_kernel,
-        grid=(nq, combined.shape[1] // TILE),
+        grid=(nq, lam_p // TILE),
         in_specs=[
-            pl.BlockSpec((1, TILE), lambda q, i: (q, i)),
-            pl.BlockSpec((1, T), lambda q, i: (q, 0)),
+            pl.BlockSpec((1, 1, TILE), lambda q, i: (q, 0, i)),
+            pl.BlockSpec((1, T, 1), lambda q, i: (q, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, T), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, T), lambda q, i: (q, 0)),
+            pl.BlockSpec((1, T, 1), lambda q, i: (q, 0, 0)),
+            pl.BlockSpec((1, T, 1), lambda q, i: (q, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, T), jnp.float32),
-            jax.ShapeDtypeStruct((nq, T), jnp.float32),
+            jax.ShapeDtypeStruct((nq, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nq, T, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
-    )(combined, thetas)
-    return counts, recsum
+    )(combined.reshape(nq, 1, lam_p), thetas.reshape(nq, T, 1))
+    return counts[:, :, 0], recsum[:, :, 0]

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 TILE = 1024  # per-grid-step λ tile
 
@@ -58,6 +57,6 @@ def prefix_sum(x: jax.Array, interpret: bool = False) -> jax.Array:
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(x)
     return out[:lam]

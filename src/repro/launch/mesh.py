@@ -4,14 +4,12 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
+    return jax.make_mesh(
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
@@ -20,7 +18,7 @@ def make_host_mesh(model: int = 1):
     """Whatever devices exist locally (tests/examples): (data, model) mesh."""
     n = len(jax.devices())
     assert n % model == 0
-    return compat.make_mesh(
+    return jax.make_mesh(
         (n // model, model), ("data", "model"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )

@@ -50,8 +50,8 @@ def compact_tail(store, tail_start: int):
     tail_start = int(tail_start)
     if not (0 <= tail_start < lam):
         raise ValueError(f"tail_start {tail_start} outside [0, {lam})")
-    dims_flat = np.asarray(store.dims).reshape(-1, store.dims.shape[-1])[:n]
-    meas_flat = np.asarray(store.measures).reshape(-1, store.measures.shape[-1])[:n]
+    dims_flat = store.dims.reshape(-1, store.dims.shape[-1])[:n]
+    meas_flat = store.measures.reshape(-1, store.measures.shape[-1])[:n]
     lo = tail_start * rpb
     # lexsort's last key is the primary: feed columns reversed so attr 0 is major
     order = np.lexsort(dims_flat[lo:].T[::-1])

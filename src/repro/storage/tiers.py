@@ -33,8 +33,10 @@ medium a block is served from — never the data.
 Tier 0 and the device fill path
 -------------------------------
 A tier constructed with ``device=True`` holds its slabs as **jax Arrays**
-(device buffers).  Its fill path is :meth:`repro.data.block_store.BlockStore.
-fetch_device` — the one-launch Pallas union gather — when ``device_fill`` is
+(device buffers) in the store's lane-dense device layout — ``dims [r, R]``,
+``meas [s, R]``, ``valid [R]`` per block, the record axis minor so TPU tiling
+pads nothing.  Its fill path is :meth:`repro.data.block_store.BlockStore.
+gather_device` — the one-launch Pallas union gather — when ``device_fill`` is
 enabled (auto: on TPU backends; force ``True`` to exercise the kernel in
 interpret mode), else a host fetch + upload.  Serving a host gather from a
 device slab downloads it ONCE per residency — the download is memoized as a
@@ -111,8 +113,8 @@ class Tier:
         The preset this tier prices its residents with
         (:meth:`TierStack.effective_io_time`).
     device : bool
-        ``True`` holds slabs as jax Arrays (device buffers) and fills from
-        :meth:`~repro.data.block_store.BlockStore.fetch_device`.
+        ``True`` holds slabs as lane-dense jax Arrays (device buffers) and
+        fills from :meth:`~repro.data.block_store.BlockStore.gather_device`.
     """
 
     def __init__(
@@ -214,29 +216,32 @@ class Tier:
 
 
 def _to_host(slab: tuple, device: bool) -> tuple:
-    """Host ``(dims, meas, valid, nbytes)`` view of a tier slab.  Device
-    slabs download under an explicit transfer-guard allow so callers may run
-    the surrounding loop under a ``"disallow"`` stray-transfer probe."""
+    """Host ``(dims [R,r], meas [R,s], valid [R], nbytes)`` view of a tier
+    slab.  Device slabs (lane-dense) download and transpose back to the
+    row-major host layout, under an explicit transfer-guard allow so callers
+    may run the surrounding loop under a ``"disallow"`` stray-transfer
+    probe."""
     if not device:
         return slab
     import jax
 
     with jax.transfer_guard_device_to_host("allow"):
         return (
-            np.asarray(slab[0]), np.asarray(slab[1]), np.asarray(slab[2]),
+            np.ascontiguousarray(np.asarray(slab[0]).T),
+            np.ascontiguousarray(np.asarray(slab[1]).T),
+            np.asarray(slab[2]),
             slab[3],
         )
 
 
 def _to_tier(slab: tuple, device: bool) -> tuple:
     """Convert an owned slab to a tier's residency format (upload/download)."""
-    import jax
-
     is_dev = not isinstance(slab[0], np.ndarray)
     if device and not is_dev:
         import jax.numpy as jnp
 
-        return (jnp.asarray(slab[0]), jnp.asarray(slab[1]),
+        return (jnp.asarray(np.ascontiguousarray(slab[0].T)),
+                jnp.asarray(np.ascontiguousarray(slab[1].T)),
                 jnp.asarray(slab[2]), slab[3])
     if not device and is_dev:
         return _to_host(slab, device=True)
@@ -259,7 +264,7 @@ class TierStack:
         The placement arbiter; defaults to
         :class:`~repro.storage.policy.CostAwarePolicy`.
     device_fill : bool | None
-        Fill device tiers through ``store.fetch_device`` (the Pallas union
+        Fill device tiers through ``store.gather_device`` (the Pallas union
         gather).  ``None`` auto-selects: the kernel path on TPU backends, a
         host fetch + upload elsewhere (interpret-mode gathers are correct
         but slow).  Force ``True`` to exercise the kernel fill anywhere.
@@ -439,7 +444,7 @@ class TierStack:
         residency without a device→host transfer, filling misses through
         :meth:`ensure` first and uploading lower-tier residents on demand.
         Returns jax ``(dims [B,R,r], meas [B,R,s], valid [B,R])``
-        byte-identical to ``store.fetch_device(block_ids)``.  Requires tier
+        byte-identical to ``store.fetch(block_ids)``.  Requires tier
         0 to be a device tier.  (The ``run_batch`` loops do NOT use this —
         they mask records on the host and go through :meth:`get_many`.)"""
         import jax.numpy as jnp
@@ -449,6 +454,15 @@ class TierStack:
         ids = np.asarray(block_ids, dtype=np.int64).ravel()
         if ids.size == 0:
             return store.fetch_device(ids)
+
+        def reread(g: np.ndarray):
+            """Accounted backing-store read of `g`, lane-dense device rows."""
+            self.stats.store_fetch_calls += 1
+            self.stats.store_blocks_fetched += int(g.size)
+            if self.fetch_log is not None:
+                self.fetch_log.append(g)
+            return store.gather_device(g)[:3]
+
         pre = {int(b) for b in ids if self._find(int(b)) is not None}
         self.ensure(store, ids)
         # device gathers are logical accesses like any other: they feed the
@@ -470,12 +484,7 @@ class TierStack:
         gone_off: dict[int, int] = {}
         gd = gm = gv = None
         if gone:
-            g = np.asarray(gone, dtype=np.int64)
-            self.stats.store_fetch_calls += 1
-            self.stats.store_blocks_fetched += len(gone)
-            if self.fetch_log is not None:
-                self.fetch_log.append(g)
-            gd, gm, gv = store.fetch_device(g)
+            gd, gm, gv = reread(np.asarray(gone, dtype=np.int64))
             gone_off = {b: off for off, b in enumerate(gone)}
         out_d, out_m, out_v = [], [], []
         tier0 = self.tiers[0]
@@ -496,17 +505,14 @@ class TierStack:
                 if raw is None:
                     # residency vanished mid-gather (peer died/evicted): one
                     # accounted re-read keeps the gather byte-identical
-                    one = np.asarray([b], dtype=np.int64)
-                    self.stats.store_fetch_calls += 1
-                    self.stats.store_blocks_fetched += 1
-                    if self.fetch_log is not None:
-                        self.fetch_log.append(one)
-                    d1, m1, v1 = store.fetch_device(one)
+                    d1, m1, v1 = reread(np.asarray([b], dtype=np.int64))
                     out_d.append(d1[0]); out_m.append(m1[0]); out_v.append(v1[0])
                     continue
                 entry = _to_tier(raw, device=True)
             out_d.append(entry[0]); out_m.append(entry[1]); out_v.append(entry[2])
-        return jnp.stack(out_d), jnp.stack(out_m), jnp.stack(out_v)
+        # tier-0 slabs are lane-dense; callers see fetch()'s row-major layout
+        return (jnp.stack(out_d).transpose(0, 2, 1),
+                jnp.stack(out_m).transpose(0, 2, 1), jnp.stack(out_v))
 
     # ------------------------------------------------------------- placement
     def _drop(self, tier_idx: int, block_id: int, entry: tuple) -> None:
@@ -600,7 +606,7 @@ class TierStack:
     def _fetch_and_admit(self, store: "BlockStore", miss: np.ndarray) -> dict:
         """Read `miss` (ascending) from the backing store and admit each
         block at its policy-chosen tier.  Device-tier admissions fill through
-        ``store.fetch_device`` (the HBM fill path) when enabled; everything
+        ``store.gather_device`` (the HBM fill path) when enabled; everything
         else through one host ``store.fetch``.  Returns
         ``block_id -> (dims, meas, valid)`` for the in-scope miss batch,
         host or device arrays as fetched — the gather fallback when a budget
@@ -655,7 +661,7 @@ class TierStack:
             calls += 1
             if self.fetch_log is not None:
                 self.fetch_log.append(dev_ids)
-            dd, dm, dv = store.fetch_device(dev_ids)
+            dd, dm, dv, _ = store.gather_device(dev_ids)
             for off, b in enumerate(dev_ids):
                 slab_dev = (dd[off], dm[off], dv[off])
                 nbytes = sum(int(a.nbytes) for a in slab_dev)

@@ -3,88 +3,8 @@ import os
 # Tests must see exactly 1 CPU device (dry-run sets 512 in its own process).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import sys
-import zlib
-
 import numpy as np
 import pytest
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback: this container is offline and has no hypothesis wheel.
-# The test files only use @given/@settings with integers/sampled_from/lists
-# strategies, so a minimal seeded-random shim keeps them collectable and
-# deterministic everywhere.  When real hypothesis is installed it wins.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import random
-    import types
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example_from(self, rnd: "random.Random"):
-            return self._draw(rnd)
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda r: r.randint(min_value, max_value))
-
-    def _sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda r: elements[r.randrange(len(elements))])
-
-    def _lists(elements, min_size=0, max_size=10):
-        def draw(r):
-            n = r.randint(min_size, max_size)
-            return [elements.example_from(r) for _ in range(n)]
-
-        return _Strategy(draw)
-
-    _DEFAULT_MAX_EXAMPLES = 20
-
-    def _given(*strategies):
-        def decorate(fn):
-            def runner():
-                n = getattr(runner, "_max_examples", _DEFAULT_MAX_EXAMPLES)
-                base = zlib.crc32(f"{fn.__module__}.{fn.__qualname__}".encode())
-                for i in range(n):
-                    rnd = random.Random(base + i)
-                    args = [s.example_from(rnd) for s in strategies]
-                    try:
-                        fn(*args)
-                    except Exception as e:
-                        raise AssertionError(
-                            f"falsifying example (shim draw {i}): "
-                            f"{fn.__name__}({', '.join(map(repr, args))})"
-                        ) from e
-
-            runner.__name__ = fn.__name__
-            runner.__qualname__ = fn.__qualname__
-            runner.__module__ = fn.__module__
-            runner.__doc__ = fn.__doc__
-            return runner
-
-        return decorate
-
-    def _settings(max_examples=_DEFAULT_MAX_EXAMPLES, deadline=None, **_kw):
-        def decorate(fn):
-            fn._max_examples = max_examples
-            return fn
-
-        return decorate
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

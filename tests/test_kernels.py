@@ -13,7 +13,7 @@ def _arr(shape, dtype=np.float32, scale=1.0):
     return jnp.asarray((RNG.normal(0, 1, shape) * scale).astype(dtype))
 
 
-@pytest.mark.parametrize("rows,lam", [(4, 100), (16, 513), (64, 2048)])
+@pytest.mark.parametrize("rows,lam", [(4, 100), (16, 513), (64, 2048), (16, 1537)])
 @pytest.mark.parametrize("gamma", [1, 2, 5])
 @pytest.mark.parametrize("op", ["and", "or"])
 def test_density_combine_sweep(rows, lam, gamma, op):
@@ -63,7 +63,9 @@ def test_theta_stats_sweep(lam, T):
     np.testing.assert_allclose(r1, r2, rtol=1e-5, atol=1e-3)
 
 
-@pytest.mark.parametrize("nq,lam,T", [(1, 100, 8), (4, 4096, 16), (9, 1000, 16)])
+@pytest.mark.parametrize(
+    "nq,lam,T", [(1, 100, 8), (4, 4096, 16), (9, 1000, 16), (3, 2049, 8)]
+)
 def test_theta_stats_batch_sweep(nq, lam, T):
     comb = jnp.asarray(
         (RNG.random((nq, lam)) * (RNG.random((nq, lam)) < 0.4)).astype(np.float32)
@@ -84,10 +86,13 @@ def test_theta_stats_batch_sweep(nq, lam, T):
         np.testing.assert_allclose(np.asarray(rb)[q], np.asarray(r1), rtol=1e-6)
 
 
-@pytest.mark.parametrize("lam,r,d", [(16, 8, 2), (100, 32, 1), (257, 16, 3)])
+@pytest.mark.parametrize(
+    "lam,r,d", [(16, 8, 2), (100, 32, 1), (257, 16, 3), (12, 8, 256), (9, 2, 128)]
+)
 def test_block_gather_sweep(lam, r, d):
-    """One-launch union gather vs the pure indexing oracle, incl. 2-D slabs,
-    repeated ids, and the empty union."""
+    """One-launch union gather vs the pure indexing oracle, incl. the store's
+    lane-dense ``[λ, r, R]`` slabs (R = d minor), 2-D slabs, repeated ids,
+    and the empty union."""
     slab = jnp.asarray(RNG.random((lam, r, d)).astype(np.float32))
     ids = jnp.asarray(RNG.integers(0, lam, 7).astype(np.int32))
     np.testing.assert_array_equal(

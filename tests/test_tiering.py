@@ -312,6 +312,35 @@ def test_get_device_serves_tier0_residency():
     assert all(stack.accesses(int(b)) == 2 for b in ids)
 
 
+@pytest.mark.parametrize("rpb", [16, 64, 128])
+@pytest.mark.parametrize("ids", [[0], [5, 2], [93, 0, 7], [4, 4, 1, 93, 8], list(range(9))])
+def test_lane_dense_store_device_paths_match_host(rpb, ids):
+    """The device copy is lane-dense (record axis minor) and every device
+    read path — ``fetch_device``, ``TierStack.get_device`` and host gathers
+    of tier-0 slabs — returns the host ``fetch`` bytes, including repeated
+    ids, union sizes on both sides of a power-of-two bucket, and the last
+    (partial) block whose row validity is derived on the device."""
+    table = _make_table("clustered", 4, n=6_001)  # a partial last block
+    store = build_block_store(table, rpb)
+    lam, r, s = store.num_blocks, table.dims.shape[1], table.measures.shape[1]
+    assert store.dims_dev.shape == (lam, r, rpb)
+    assert store.meas_dev.shape == (lam, s, rpb)
+    ids = np.asarray([b % lam if b < 93 else lam - 1 for b in ids])
+    want = store.fetch(ids)
+    for i in np.flatnonzero(ids == lam - 1):
+        assert 0 < want[2][i].sum() == table.num_records - (lam - 1) * rpb < rpb
+    stack = make_tier_stack(None, None, device_fill=True)
+    for got in (store.fetch_device(ids), stack.get_device(store, ids),
+                stack.get_many(store, ids)):
+        for g, w in zip(got, want):
+            g = np.asarray(g)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+    # tier-0 residency holds lane-dense per-block slabs
+    entry = stack.tiers[0].peek(int(ids[0]))
+    assert entry[0].shape == (r, rpb) and entry[1].shape == (s, rpb)
+
+
 def test_host_gather_of_device_slab_memoizes_one_download():
     """A device-tier resident serves host gathers through a memoized host
     mirror: one device→host download per residency, not one per access —
