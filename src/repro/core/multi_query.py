@@ -74,6 +74,7 @@ from repro.core.forward_optimal import forward_optimal_faithful
 from repro.core.predicates import Predicate
 from repro.core.threshold import threshold_cut, threshold_sort_batch
 from repro.core.two_prong import two_prong_select_batch
+from repro.obs import span
 
 # repro.kernels.plan_wave is imported lazily inside the device-pipeline
 # functions: pulling it here would make every host-only any_k_batch call pay
@@ -469,57 +470,67 @@ def _execute_wave(
     post-fetch bookkeeping (mask, record append, exclusion growth, refill
     accounting).  Shared verbatim by the host-mirror and device plan loops so
     the only thing that differs between them is where plans are computed.
+    Traced as ``wave.execute`` with two children: ``tier.fill`` (the union's
+    tier fill) and ``wave.filter`` (the per-query gather and row filter).
     Returns ``(progressed, blocks_requested_delta)``."""
     obs = getattr(engine, "obs", None)
-    if obs is not None:
-        with obs.span("wave.execute", n_active=len(active)) as sp:
-            progressed, requested = _execute_wave_body(
-                engine, cache, active, wave_blocks, touched, touched_set
-            )
+    with span(obs, "wave.execute", n_active=len(active)) as sp:
+        union = np.unique(np.concatenate(wave_blocks)) if wave_blocks else np.asarray([])
+        if union.size:
+            for b in union:
+                if int(b) not in touched_set:
+                    touched_set.add(int(b))
+                    touched.append(int(b))
+            with span(obs, "tier.fill") as fill:
+                if obs is not None:
+                    fill.set(**_fill_sources(cache, union))
+                cache.ensure(engine.store, union)
+        progressed = False
+        requested = 0
+        with span(obs, "wave.filter") as filt:
+            if obs is not None:
+                got0 = sum(st.got for st in active)
+            for st, blocks in zip(active, wave_blocks):
+                if blocks.size == 0:
+                    continue
+                progressed = True
+                bd, bm, bv = cache.get_many(engine.store, blocks)
+                mask = np.asarray(engine._mask(bd, st.query.predicates, st.query.op) & bv)
+                bi, ri = np.nonzero(mask)
+                st.rec_blocks.append(blocks[bi])
+                st.rec_rows.append(ri)
+                st.meas.append(np.asarray(bm)[bi, ri])
+                st.planned.append(blocks)
+                requested += int(blocks.size)
+                st.got += int(bi.size)
+                st.exclude = np.concatenate([st.exclude, blocks])
+                st.need = st.query.k - st.got
+                st.rounds += 1
+                if st.got >= st.query.k:
+                    st.done = True
+            if obs is not None:
+                filt.set(blocks=requested, rows_kept=sum(st.got for st in active) - got0)
+        if obs is not None:
             sp.set(requested=requested, progressed=progressed,
                    satisfied=sum(1 for st in active if st.done))
-            return progressed, requested
-    return _execute_wave_body(
-        engine, cache, active, wave_blocks, touched, touched_set
-    )
-
-
-def _execute_wave_body(
-    engine: "NeedleTailEngine",
-    cache,
-    active: list[_QueryState],
-    wave_blocks: list[np.ndarray],
-    touched: list[int],
-    touched_set: set[int],
-) -> tuple[bool, int]:
-    union = np.unique(np.concatenate(wave_blocks)) if wave_blocks else np.asarray([])
-    if union.size:
-        for b in union:
-            if int(b) not in touched_set:
-                touched_set.add(int(b))
-                touched.append(int(b))
-        cache.ensure(engine.store, union)
-    progressed = False
-    requested = 0
-    for st, blocks in zip(active, wave_blocks):
-        if blocks.size == 0:
-            continue
-        progressed = True
-        bd, bm, bv = cache.get_many(engine.store, blocks)
-        mask = np.asarray(engine._mask(bd, st.query.predicates, st.query.op) & bv)
-        bi, ri = np.nonzero(mask)
-        st.rec_blocks.append(blocks[bi])
-        st.rec_rows.append(ri)
-        st.meas.append(np.asarray(bm)[bi, ri])
-        st.planned.append(blocks)
-        requested += int(blocks.size)
-        st.got += int(bi.size)
-        st.exclude = np.concatenate([st.exclude, blocks])
-        st.need = st.query.k - st.got
-        st.rounds += 1
-        if st.got >= st.query.k:
-            st.done = True
     return progressed, requested
+
+
+def _fill_sources(cache, union: np.ndarray) -> dict:
+    """Where the fill's blocks were before it: ``union`` blocks, and how
+    many sat in each tier (``in_<tier>``) or came ``from_store``.  A cache
+    without tiers reports its residents as ``in_cache``."""
+    where = getattr(cache, "residency_tier", None)
+    if where is None:
+        resident = sum(1 for b in union if int(b) in cache)
+        return {"union": int(union.size), "in_cache": resident,
+                "from_store": int(union.size) - resident}
+    at = where(union)
+    out = {"union": int(union.size)}
+    for t, tier in enumerate(cache.tiers):
+        out[f"in_{tier.name}"] = int(np.count_nonzero(at == t))
+    out["from_store"] = int(np.count_nonzero(at == len(cache.tiers)))
+    return out
 
 
 def new_query_state(query: "BatchQuery | tuple") -> _QueryState:
@@ -759,47 +770,51 @@ class DeviceWave:
         """One ⊕-combine per op group for the queued joiners (the
         :func:`repro.kernels.plan_wave.combine_wave` fold — bit-identical to
         the host combine; Predicate trees compile host-side and upload), then
-        one scatter seats them all."""
+        one scatter seats them all.  Traced as ``plan.join``."""
         if not self._joining:
             return
         from repro.kernels.plan_wave import combine_wave, join_wave_slots
 
         joining, self._joining = self._joining, []
         engine = self.engine
-        dens_dev = engine.store.index.densities  # [rows, λ] jax Array, resident
-        vocab = engine.store.index.vocab
-        rows: list = [None] * len(joining)
-        groups: dict[str, list[int]] = {}
-        for j, slot in enumerate(joining):
-            st = self.slots[slot]
-            if isinstance(st.query.predicates, Predicate):
-                rows[j] = jnp.asarray(
-                    np.asarray(
-                        st.query.predicates.density(engine.store.index),
-                        dtype=np.float32,
+        obs = getattr(engine, "obs", None)
+        with span(obs, "plan.join") as sp:
+            dens_dev = engine.store.index.densities  # [rows, λ] jax Array, resident
+            vocab = engine.store.index.vocab
+            rows: list = [None] * len(joining)
+            groups: dict[str, list[int]] = {}
+            for j, slot in enumerate(joining):
+                st = self.slots[slot]
+                if isinstance(st.query.predicates, Predicate):
+                    rows[j] = jnp.asarray(
+                        np.asarray(
+                            st.query.predicates.density(engine.store.index),
+                            dtype=np.float32,
+                        )
                     )
+                else:
+                    groups.setdefault(st.query.op, []).append(j)
+            for op, js in groups.items():
+                rm = pack_row_matrix(
+                    vocab, [self.slots[joining[j]].query.predicates for j in js]
                 )
-            else:
-                groups.setdefault(st.query.op, []).append(j)
-        for op, js in groups.items():
-            rm = pack_row_matrix(
-                vocab, [self.slots[joining[j]].query.predicates for j in js]
+                rows_dev = combine_wave(dens_dev, jnp.asarray(rm), op)
+                for off, j in enumerate(js):
+                    rows[j] = rows_dev[off]
+            excl_rows = np.zeros((len(joining), self.lam), dtype=bool)
+            for j, slot in enumerate(joining):
+                ex = self.slots[slot].exclude
+                if ex.size:
+                    excl_rows[j, ex] = True
+            c0, ex, th, tp = join_wave_slots(
+                self.state.combined0, self.state.excl, self.state.th_mask,
+                self.state.tp_win, jnp.asarray(np.asarray(joining, np.int32)),
+                jnp.stack(rows), jnp.asarray(excl_rows),
             )
-            rows_dev = combine_wave(dens_dev, jnp.asarray(rm), op)
-            for off, j in enumerate(js):
-                rows[j] = rows_dev[off]
-        excl_rows = np.zeros((len(joining), self.lam), dtype=bool)
-        for j, slot in enumerate(joining):
-            ex = self.slots[slot].exclude
-            if ex.size:
-                excl_rows[j, ex] = True
-        c0, ex, th, tp = join_wave_slots(
-            self.state.combined0, self.state.excl, self.state.th_mask,
-            self.state.tp_win, jnp.asarray(np.asarray(joining, np.int32)),
-            jnp.stack(rows), jnp.asarray(excl_rows),
-        )
-        self.state.combined0, self.state.excl = c0, ex
-        self.state.th_mask, self.state.tp_win = th, tp
+            self.state.combined0, self.state.excl = c0, ex
+            self.state.th_mask, self.state.tp_win = th, tp
+            if obs is not None:
+                sp.set(joiners=len(joining), groups=len(groups))
 
     def plan_round(self) -> tuple[list[_QueryState], list[np.ndarray]]:
         """One device planning round over the current occupants.
@@ -811,85 +826,95 @@ class DeviceWave:
         ``(active_states, wave_blocks)`` in slot order, ready for
         :func:`_execute_wave` — both empty when no slot is occupied (in
         which case no transfer is shipped).
-        """
-        self._flush_joins()
-        active_slots = self.busy_slots()
-        active = [self.slots[s] for s in active_slots]
-        if not active:
-            return [], []
-        from repro.kernels.plan_wave import unpack_plan
 
+        Traced as ``plan.round`` (``site="device"``) with three children:
+        ``plan.join`` (the joiners' flush, when any are queued),
+        ``plan.device_round`` (the round program's call until its packed
+        plan is on the host) and ``plan.decode`` (unpacking, the §7.2
+        arbitration and the exclusion diff).
+        """
         engine = self.engine
-        dstate = self.state
-        needs_np = np.ones((self.qb,), np.float32)
-        for s, st in zip(active_slots, active):
-            needs_np[s] = float(st.need)
-        packed, excl, th_prev, tp_prev = self.round_fn(
-            dstate.combined0, dstate.excl, dstate.th_mask, dstate.tp_win,
-            jnp.asarray(self.chosen), jnp.asarray(needs_np),
-        )
-        dstate.excl, dstate.th_mask, dstate.tp_win = excl, th_prev, tp_prev
-        # the round's single device→host transfer: the packed [Q, λ+3] plan.
-        # Explicitly allowed so callers can run the whole loop under
-        # jax.transfer_guard_device_to_host("disallow") as a stray-transfer
-        # probe (benchmarks/common.py).
-        with jax.transfer_guard_device_to_host("allow"):
-            packed_np = np.asarray(packed)
-        dstate.transfers += 1
         obs = getattr(engine, "obs", None)
-        if obs is not None:
-            obs.event("device.transfer", n=dstate.transfers,
-                      nbytes=int(packed_np.nbytes), n_active=len(active))
-        th_mask, _, tps, tpe = unpack_plan(packed_np, self.lam)
-        # forward_optimal falls back to the host DP (sequential by nature);
-        # its combined rows come from the host mirror, not the device
-        fo_active = [
-            st for st in active
-            if (st.query.algo or self.default_algo) == "forward_optimal"
-        ]
-        fo_plans: dict[int, np.ndarray] = {}
-        if fo_active:
-            fo_combined = _combined_matrix(engine, fo_active)
-            for st, comb in zip(fo_active, fo_combined):
-                sel, _ = forward_optimal_faithful(comb, st.need, self.rpb, engine.cost)
-                fo_plans[id(st)] = np.asarray(sel, dtype=np.int64)
-        self.chosen = np.full((self.qb,), -1, np.int8)
-        wave_blocks: list[np.ndarray] = []
-        for s, st in zip(active_slots, active):
-            a = st.query.algo or self.default_algo
-            if a == "forward_optimal":
-                plan = fo_plans[id(st)]
-                st.used_algo = a
-            elif a == "threshold":
-                plan = np.flatnonzero(th_mask[s]).astype(np.int64)
-                self.chosen[s] = 0
-                st.used_algo = a
-            elif a == "two_prong":
-                plan = np.arange(int(tps[s]), int(tpe[s]), dtype=np.int64)
-                self.chosen[s] = 1
-                st.used_algo = a
-            else:  # auto — §7.2: cost both on host (the cost model is f64 host code)
-                bt = np.flatnonzero(th_mask[s]).astype(np.int64)
-                b2 = np.arange(int(tps[s]), int(tpe[s]), dtype=np.int64)
-                cost_fn = getattr(engine, "plan_cost", None) or engine.cost.io_time
-                ct, c2 = cost_fn(bt), cost_fn(b2)
-                if ct <= c2:
-                    plan, self.chosen[s], st.used_algo = bt, 0, "threshold"
-                else:
-                    plan, self.chosen[s], st.used_algo = b2, 1, "two_prong"
-            blocks = np.setdiff1d(plan, st.exclude)
-            if blocks.size == 0:
-                st.done = True  # plan exhausted: nothing new to read
-            wave_blocks.append(blocks)
-        if obs is not None:
-            choices: dict[str, int] = {}
-            for st in active:
-                choices[st.used_algo] = choices.get(st.used_algo, 0) + 1
-            union = (np.unique(np.concatenate(wave_blocks))
-                     if wave_blocks else np.asarray([], dtype=np.int64))
-            obs.event("plan.round", site="device", n_active=len(active),
-                      n_blocks=int(union.size), choices=choices,
-                      predicted_io_s=float(engine.cost.io_time(union)))
+        with span(obs, "plan.round", site="device") as sp:
+            self._flush_joins()
+            active_slots = self.busy_slots()
+            active = [self.slots[s] for s in active_slots]
+            if not active:
+                return [], []
+            from repro.kernels.plan_wave import unpack_plan
+
+            dstate = self.state
+            needs_np = np.ones((self.qb,), np.float32)
+            for s, st in zip(active_slots, active):
+                needs_np[s] = float(st.need)
+            with span(obs, "plan.device_round") as dr:
+                packed, excl, th_prev, tp_prev = self.round_fn(
+                    dstate.combined0, dstate.excl, dstate.th_mask, dstate.tp_win,
+                    jnp.asarray(self.chosen), jnp.asarray(needs_np),
+                )
+                dstate.excl, dstate.th_mask, dstate.tp_win = excl, th_prev, tp_prev
+                # the round's single device→host transfer: the packed [Q, λ+3] plan.
+                # Explicitly allowed so callers can run the whole loop under
+                # jax.transfer_guard_device_to_host("disallow") as a stray-transfer
+                # probe (benchmarks/common.py).
+                with jax.transfer_guard_device_to_host("allow"):
+                    packed_np = np.asarray(packed)
+                if obs is not None:
+                    dr.set(nbytes=int(packed_np.nbytes))
+            dstate.transfers += 1
+            if obs is not None:
+                obs.event("device.transfer", n=dstate.transfers,
+                          nbytes=int(packed_np.nbytes), n_active=len(active))
+            with span(obs, "plan.decode"):
+                th_mask, _, tps, tpe = unpack_plan(packed_np, self.lam)
+                # forward_optimal falls back to the host DP (sequential by nature);
+                # its combined rows come from the host mirror, not the device
+                fo_active = [
+                    st for st in active
+                    if (st.query.algo or self.default_algo) == "forward_optimal"
+                ]
+                fo_plans: dict[int, np.ndarray] = {}
+                if fo_active:
+                    fo_combined = _combined_matrix(engine, fo_active)
+                    for st, comb in zip(fo_active, fo_combined):
+                        sel, _ = forward_optimal_faithful(comb, st.need, self.rpb, engine.cost)
+                        fo_plans[id(st)] = np.asarray(sel, dtype=np.int64)
+                self.chosen = np.full((self.qb,), -1, np.int8)
+                wave_blocks: list[np.ndarray] = []
+                for s, st in zip(active_slots, active):
+                    a = st.query.algo or self.default_algo
+                    if a == "forward_optimal":
+                        plan = fo_plans[id(st)]
+                        st.used_algo = a
+                    elif a == "threshold":
+                        plan = np.flatnonzero(th_mask[s]).astype(np.int64)
+                        self.chosen[s] = 0
+                        st.used_algo = a
+                    elif a == "two_prong":
+                        plan = np.arange(int(tps[s]), int(tpe[s]), dtype=np.int64)
+                        self.chosen[s] = 1
+                        st.used_algo = a
+                    else:  # auto — §7.2: cost both on host (the cost model is f64 host code)
+                        bt = np.flatnonzero(th_mask[s]).astype(np.int64)
+                        b2 = np.arange(int(tps[s]), int(tpe[s]), dtype=np.int64)
+                        cost_fn = getattr(engine, "plan_cost", None) or engine.cost.io_time
+                        ct, c2 = cost_fn(bt), cost_fn(b2)
+                        if ct <= c2:
+                            plan, self.chosen[s], st.used_algo = bt, 0, "threshold"
+                        else:
+                            plan, self.chosen[s], st.used_algo = b2, 1, "two_prong"
+                    blocks = np.setdiff1d(plan, st.exclude)
+                    if blocks.size == 0:
+                        st.done = True  # plan exhausted: nothing new to read
+                    wave_blocks.append(blocks)
+            if obs is not None:
+                choices: dict[str, int] = {}
+                for st in active:
+                    choices[st.used_algo] = choices.get(st.used_algo, 0) + 1
+                union = (np.unique(np.concatenate(wave_blocks))
+                         if wave_blocks else np.asarray([], dtype=np.int64))
+                sp.set(n_active=len(active), n_blocks=int(union.size), choices=choices,
+                       predicted_io_s=float(engine.cost.io_time(union)))
         return active, wave_blocks
 
 

@@ -10,7 +10,7 @@ disabled recorder performs zero clock reads and zero per-event allocations
 (see :mod:`repro.obs.trace`).
 """
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_SPAN, TraceRecorder
+from repro.obs.trace import NULL_SPAN, TraceRecorder, span
 from repro.obs.wave_stats import (
     WAVE_STATS_KEYS, make_wave_stats, record_wave_metrics,
 )
@@ -22,4 +22,5 @@ __all__ = [
     "WAVE_STATS_KEYS",
     "make_wave_stats",
     "record_wave_metrics",
+    "span",
 ]

@@ -24,6 +24,14 @@ Design contract
   unchanged with tracing on or off — tracing observes, never steers.
 * **Single-threaded** — the recorder is wired on the serving thread only;
   the async prefetch worker never emits (its results are traced at drain).
+* **One clock with the device trace** — a live span also holds a
+  ``jax.profiler.TraceAnnotation`` of its name for its duration, so under
+  ``jax.profiler`` every span lies in the profiler's host plane, on the
+  device trace's own clock (a no-op while no profile is being taken).
+
+:func:`span` is the one way a call site opens a span when its recorder may
+be ``None``: it hands back :data:`NULL_SPAN` then, so a traced site needs no
+untraced twin of its body.
 
 Span nesting is tracked with an explicit stack: a span opened while another
 is active records it as its parent, so one serving tick yields a tree
@@ -36,6 +44,8 @@ import itertools
 import json
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
 
@@ -59,9 +69,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span: times itself on enter/exit and emits one record."""
+    """A live span: times itself on enter/exit, mirrors itself into the
+    profiler's host trace, and emits one record."""
 
-    __slots__ = ("rec", "name", "attrs", "sid", "parent", "t0")
+    __slots__ = ("rec", "name", "attrs", "sid", "parent", "t0", "ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, attrs: dict):
         self.rec = rec
@@ -73,6 +84,8 @@ class _Span:
         self.sid = next(rec._ids)
         self.parent = rec._stack[-1] if rec._stack else 0
         rec._stack.append(self.sid)
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
         self.t0 = rec.clock()
         return self
 
@@ -85,6 +98,7 @@ class _Span:
     def __exit__(self, *exc) -> bool:
         rec = self.rec
         t1 = rec.clock()
+        self.ann.__exit__(*exc)
         rec._stack.pop()
         e = {"kind": "span", "name": self.name, "id": self.sid,
              "parent": self.parent, "t0": self.t0, "t1": t1}
@@ -151,3 +165,12 @@ class TraceRecorder:
             for e in self.events:
                 f.write(json.dumps(e, sort_keys=True) + "\n")
         return str(path)
+
+
+def span(obs: "TraceRecorder | None", name: str, **attrs):
+    """``obs.span(name, **attrs)``, or the shared :data:`NULL_SPAN` when
+    ``obs`` is ``None``: no clock read and no record.  Attributes that cost
+    work to compute belong in a ``set`` under ``if obs is not None``."""
+    if obs is None:
+        return NULL_SPAN
+    return obs.span(name, **attrs)

@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import decode as D
+from repro.obs import span
 from repro.serving.admission import AdmissionController, AdmissionPolicy
 
 
@@ -1136,11 +1137,12 @@ class ServeEngine:
         that actually ran a round this tick (the aggregate ledger carries
         ``"kind": "aggregate"``)."""
         out = {"lm": [], "exemplar": [], "aggregate": []}
-        if self._prefill is not None and (self.queue or self._lm is not None):
-            out["lm"] = self.lm_tick()
-        if engine is not None:
-            out["exemplar"] = self.exemplar_tick(engine, now=now, drain=drain)
-            out["aggregate"] = self.aggregate_tick(engine, now=now, drain=drain)
+        with span(getattr(self, "obs", None), "serve.step"):
+            if self._prefill is not None and (self.queue or self._lm is not None):
+                out["lm"] = self.lm_tick()
+            if engine is not None:
+                out["exemplar"] = self.exemplar_tick(engine, now=now, drain=drain)
+                out["aggregate"] = self.aggregate_tick(engine, now=now, drain=drain)
         return out
 
     def run_continuous(self, engine=None, max_ticks: int = 100_000,

@@ -92,6 +92,8 @@ class TierStats:
     demotions_out: int = 0  # residents displaced down out of this tier
     evictions: int = 0  # residents dropped out of the stack from here
     invalidations: int = 0  # residents evicted by append invalidation
+    host_downloads: int = 0  # device tiers: slabs downloaded to a host mirror
+    host_download_bytes: int = 0  # device tiers: bytes of those downloads
     bytes_cached: int = 0  # gauge
     blocks_cached: int = 0  # gauge
 
@@ -212,6 +214,8 @@ class Tier:
         if mirror is None:
             mirror = _to_host(entry, device=True)
             self._host_mirror[int(block_id)] = mirror
+            self.stats.host_downloads += 1
+            self.stats.host_download_bytes += entry[3]
         return mirror
 
 
@@ -856,6 +860,9 @@ class TierStack:
             for k in ("hits", "admissions", "promotions_in", "demotions_in",
                       "demotions_out", "evictions", "invalidations"):
                 out[f"{tier.name}.{k}"] = getattr(s, k)
+            if tier.device:  # slabs the host row filter pulled off the device
+                out[f"{tier.name}.host_downloads"] = s.host_downloads
+                out[f"{tier.name}.host_download_bytes"] = s.host_download_bytes
             extra = getattr(tier, "extra_counters", None)
             if extra is not None:  # e.g. peer.remote_fetches / peer.migrations
                 for k, v in extra().items():

@@ -334,3 +334,122 @@ def test_fetch_events_carry_predicted_vs_observed_io(store):
         assert a["predicted_io_s"] >= 0.0
         assert a["observed_io_s"] >= 0.0
         assert a["level"]
+
+
+# ---------------------------------------------------------------------------
+# The device refill round and the tier/filter path: spans, the profiler
+# mirror, the slab-download counter, and nothing at all with obs=None.
+# ---------------------------------------------------------------------------
+def _device_serve(store, obs, n_slots=2):
+    from repro.storage import make_tier_stack
+
+    eng = NeedleTailEngine(store, tiers=make_tier_stack(1 << 20, 1 << 20))
+    serve = ServeEngine(None, None, max_slots=n_slots, exemplar_device=True,
+                        exemplar_policy=AdmissionPolicy(max_wave=n_slots), obs=obs)
+    reqs = [serve.submit_exemplar_request(q.predicates, q.k) for q in _queries()]
+    for _ in range(64):
+        if all(r.done for r in reqs):
+            break
+        serve.step(eng, drain=True)
+    assert all(r.done for r in reqs)
+    return eng, reqs
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enters and exits."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_span_helper_and_profiler_mirror(monkeypatch):
+    import repro.obs.trace as obs_trace
+    from repro.obs import span
+
+    assert span(None, "plan.round", site="device") is NULL_SPAN
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", _Annotations)
+    monkeypatch.setattr(_Annotations, "log", [])
+    clk = CountingClock()
+    rec = TraceRecorder(clock=clk)
+    with span(rec, "outer", a=1):
+        with span(rec, "inner"):
+            pass
+    assert _Annotations.log == [("enter", "outer"), ("enter", "inner"),
+                                ("exit", "inner"), ("exit", "outer")]
+    assert [e["name"] for e in rec.to_events()] == ["inner", "outer"]
+    assert clk.calls == 4  # still two clock reads per span
+    off = TraceRecorder(clock=clk, enabled=False)
+    assert span(off, "x") is NULL_SPAN
+
+
+def test_device_serve_without_recorder_opens_no_span(store, monkeypatch):
+    import repro.obs.trace as obs_trace
+
+    opened = []
+    monkeypatch.setattr(obs_trace._Span, "__enter__",
+                        lambda self: opened.append(self.name) or self)
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", _Annotations)
+    monkeypatch.setattr(_Annotations, "log", [])
+    _device_serve(store, None)
+    assert opened == [] and _Annotations.log == []
+    clk = CountingClock()
+    _device_serve(store, TraceRecorder(clock=clk, enabled=False))
+    assert clk.calls == 0, "disabled recorder read the clock on the device path"
+    assert opened == [] and _Annotations.log == []
+
+
+def test_device_round_spans_nest_and_feed_the_report(store):
+    from tools.trace_report import request_paths, wave_summary
+
+    rec = TraceRecorder(clock=CountingClock(dt=0.0005))
+    eng, reqs = _device_serve(store, rec)
+    events = rec.to_events()
+    spans = {e["id"]: e for e in events if e["kind"] == "span"}
+    parent = {"plan.join": "plan.round", "plan.device_round": "plan.round",
+              "plan.decode": "plan.round", "tier.fill": "wave.execute",
+              "wave.filter": "wave.execute", "serve.exemplar_tick": "serve.step"}
+    seen = set()
+    for e in spans.values():
+        if e["name"] in parent:
+            seen.add(e["name"])
+            assert spans[e["parent"]]["name"] == parent[e["name"]]
+    assert seen == set(parent)
+    rounds = [e for e in spans.values() if e["name"] == "plan.round"]
+    assert all(e["attrs"]["site"] == "device" and e["attrs"]["choices"] for e in rounds)
+    assert not [e for e in events if e["kind"] == "event" and e["name"] == "plan.round"]
+    for e in spans.values():
+        a = e.get("attrs", {})
+        if e["name"] == "tier.fill":
+            assert a["in_hbm"] + a["in_dram"] + a["from_store"] == a["union"] > 0
+        elif e["name"] == "wave.filter":
+            assert a["blocks"] > 0 and a["rows_kept"] >= 0
+        elif e["name"] == "plan.device_round":
+            assert a["nbytes"] > 0
+    # the report counts the device path's choices from its spans
+    summary = wave_summary(events)
+    assert sum(summary["plan_choices"].values()) == sum(
+        e["attrs"]["n_active"] for e in rounds)
+    assert sorted(request_paths(events)) == sorted(r.rid for r in reqs)
+
+
+def test_hbm_host_downloads_count_one_per_residency(store):
+    from repro.storage import make_tier_stack
+
+    stack = make_tier_stack(1 << 20, None)
+    slab = stack.block_nbytes(store)
+    stack.ensure(store, [1, 2, 3])
+    c0 = stack.tier_counters()
+    assert c0["hbm.host_downloads"] == 0 and "dram.host_downloads" not in c0
+    stack.get_many(store, [1, 2])
+    stack.get_many(store, [2, 3, 1])
+    c = stack.tier_counters()
+    assert c["hbm.host_downloads"] == 3
+    assert c["hbm.host_download_bytes"] == 3 * slab

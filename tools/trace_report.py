@@ -140,9 +140,6 @@ def wave_summary(events: list[dict]) -> dict:
             if e["name"] == "plan.round":
                 for algo, n in (a.get("choices") or {}).items():
                     choices[algo] += n
-        elif e["name"] == "plan.round":  # device path emits events
-            for algo, n in (a.get("choices") or {}).items():
-                choices[algo] += n
         elif e["name"] == "admission.launch":
             reasons[a.get("reason", "?")] += 1
         elif e["name"] == "fetch.store":
