@@ -2,13 +2,15 @@
 
 * :func:`two_prong_faithful` — 1:1 host port of Algorithm 2 (two-pointer walk).
 * :func:`two_prong_select` — TPU-native outcome-equivalent form: prefix sums +
-  per-start binary search (`searchsorted`) for the minimal window end, then an
-  argmin over starts.  For every start block i this computes the same "smallest
-  sequence beginning at i with ≥ k expected records" that the two-pointer walk
-  considers (Theorem 2 proof structure), so the global minimum window is identical;
-  ties resolve to the smallest start id in both.  O(λ log λ) work, O(log λ) depth —
-  the sequential walk is O(λ) work but O(λ) depth, which is the wrong trade on a
-  vector machine.
+  the minimal window end of every start, then an argmin over starts.  For every
+  start block i this computes the same "smallest sequence beginning at i with
+  ≥ k expected records" that the two-pointer walk considers (Theorem 2 proof
+  structure), so the global minimum window is identical; ties resolve to the
+  smallest start id in both.  The ends are a rank by sort-merge
+  (:func:`rank_left`): two sorts and a prefix count, O(λ log λ) work with no
+  gather, scatter or loop — the sequential walk is O(λ) work but O(λ) depth,
+  and a per-start binary search is a loop of ⌈log2 λ⌉ gathers, both the wrong
+  trade on a vector machine.
 """
 from __future__ import annotations
 
@@ -46,6 +48,31 @@ def two_prong_faithful(
     return min_start, min_end
 
 
+def rank_left(sorted_arr: jax.Array, v: jax.Array) -> jax.Array:
+    """``jnp.searchsorted(sorted_arr, v, side="left")`` for 1-D arrays, by a
+    sort-merge rank: no gather, scatter or loop.
+
+    One sort merges ``[v, sorted_arr]`` keyed on (value, tag), where query i
+    carries tag i and every ``sorted_arr`` entry tag ``len(v)``, so a query
+    sorts before an equal entry (``side="left"``).  A prefix count of the
+    entries in merged order counts those strictly below each query, and a
+    second sort on the tag brings the queries back to their own order.  The
+    value order is ``lax.sort``'s total order, the one ``searchsorted``
+    compares with.  Exact for any ``v``; equal to ``searchsorted`` whenever
+    ``sorted_arr`` is nondecreasing in that order.
+    """
+    n = v.shape[0]
+    keys = jnp.concatenate([v, sorted_arr])
+    tag = jnp.concatenate([
+        jnp.arange(n, dtype=jnp.int32),
+        jnp.full(sorted_arr.shape, n, jnp.int32),
+    ])
+    _, tag = jax.lax.sort((keys, tag), num_keys=2)
+    below = jnp.cumsum(tag == n, dtype=jnp.int32)  # entries at or before each slot
+    _, below = jax.lax.sort((tag, below), num_keys=1)
+    return below[:n]
+
+
 class TwoProngResult(NamedTuple):
     start: jax.Array  # [] int32 inclusive
     end: jax.Array  # [] int32 exclusive
@@ -62,7 +89,7 @@ def two_prong_select(
     k = jnp.asarray(k, dtype=m.dtype)
     # minimal end for each start: smallest e with c[e] >= c[i] + k
     targets = c[:-1] + k
-    ends = jnp.searchsorted(c, targets, side="left").astype(jnp.int32)  # [lam]
+    ends = rank_left(c, targets)  # [lam]
     starts = jnp.arange(lam, dtype=jnp.int32)
     feasible = ends <= lam
     lengths = jnp.where(feasible, ends - starts, jnp.iinfo(jnp.int32).max)

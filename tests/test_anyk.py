@@ -12,7 +12,9 @@ from repro.core.cost_model import make_cost_model
 from repro.core.density_map import combine_densities_np
 from repro.core.forward_optimal import forward_optimal_faithful, forward_optimal_scan
 from repro.core.threshold import threshold_faithful, threshold_select
-from repro.core.two_prong import two_prong_faithful, two_prong_select
+from repro.core.two_prong import (
+    rank_left, two_prong_faithful, two_prong_select, two_prong_select_batch,
+)
 
 RPB = 20
 
@@ -85,6 +87,93 @@ def test_two_prong_locality_optimality(seed):
                 if c[e] - c[s] >= k:
                     assert e - s >= best
                     break
+
+
+# Window-end cases for the sort-merge rank.  Densities are multiples of
+# 1/EXACT_RPB, so every prefix sum is an exact integer and a target can equal
+# a prefix sum exactly.
+EXACT_RPB = 16
+
+
+def _clustered_row(seed, lam=96):
+    """Runs of equal density between zero plateaus."""
+    rng = np.random.default_rng(seed)
+    row = np.zeros(lam, np.float32)
+    for s in rng.integers(0, lam, 6):
+        row[s : s + rng.integers(2, 12)] = rng.integers(1, EXACT_RPB + 1) / EXACT_RPB
+    return row
+
+
+def _mass(row, lo, hi):
+    return float(np.sum(row[lo:hi], dtype=np.float64) * EXACT_RPB)
+
+
+def _window_case(name):
+    """``(rows [Q, λ] f32, needs [Q] f32)`` of one named case."""
+    if name == "clustered_plateaus":
+        rows, needs = [_clustered_row(1)], [40.0]
+    elif name == "tied_densities":
+        rows = [np.tile(np.float32([2, 2, 0, 5, 5, 5]) / EXACT_RPB, 16)]
+        needs = [21.0]
+    elif name == "k_on_prefix_sum":
+        row = _clustered_row(2)
+        rows, needs = [row, row], [_mass(row, 0, 60), _mass(row, 17, 50)]
+    elif name == "infeasible":
+        row = _clustered_row(3)
+        rows, needs = [row], [_mass(row, 0, None) + 1.0]
+    elif name == "all_zero":
+        rows, needs = [np.zeros(96, np.float32)], [5.0]
+    elif name == "lambda_one":
+        row = np.float32([7 / EXACT_RPB])
+        rows, needs = [row, row, row], [3.0, 7.0, 8.0]
+    elif name == "exclusion_zeroed":
+        rng = np.random.default_rng(4)
+        dense = (rng.integers(1, EXACT_RPB + 1, 96) / EXACT_RPB).astype(np.float32)
+        rows = [np.where(rng.random(96) < p, 0.0, dense) for p in (0.3, 0.9)]
+        needs = [100.0, 30.0]
+    elif name == "batched":
+        rows = [_clustered_row(s) for s in range(5)]
+        rows += [np.zeros(96, np.float32), np.tile(rows[0][:48], 2)]
+        needs = [40.0, 1.0, _mass(rows[2], 3, 70), 1e6, 17.0, 5.0, 40.0]
+    else:  # random_float: f32 rounding in the prefix sums
+        rng = np.random.default_rng(int(name.rsplit("_", 1)[1]))
+        d = rng.random((4, 96)).astype(np.float32)
+        d[rng.random((4, 96)) < 0.5] = 0.0
+        rows, needs = list(d), list(rng.choice([1.0, 13.0, 100.0, 400.0], 4))
+    return np.stack(rows).astype(np.float32), np.asarray(needs, np.float32)
+
+
+def _ends_by_scan(row, k):
+    """Window ends as ``two_prong_select`` forms them, searched by the default
+    scan ``jnp.searchsorted``: the oracle the sort-merge rank replaces."""
+    m = jnp.asarray(row) * EXACT_RPB
+    c = jnp.concatenate([jnp.zeros((1,), m.dtype), jnp.cumsum(m)])
+    targets = c[:-1] + jnp.float32(k)
+    return c, targets, np.asarray(jnp.searchsorted(c, targets, side="left"))
+
+
+@pytest.mark.parametrize("case", [
+    "clustered_plateaus", "tied_densities", "k_on_prefix_sum", "infeasible",
+    "all_zero", "lambda_one", "exclusion_zeroed", "batched",
+    "random_float_5", "random_float_6",
+])
+def test_two_prong_window_ends_equal_searchsorted(case):
+    rows, needs = _window_case(case)
+    lam = rows.shape[1]
+    batch = two_prong_select_batch(jnp.asarray(rows), jnp.asarray(needs), EXACT_RPB)
+    for q, (row, k) in enumerate(zip(rows, needs)):
+        c, targets, ends = _ends_by_scan(row, k)
+        np.testing.assert_array_equal(np.asarray(rank_left(c, targets)), ends)
+        lengths = np.where(ends <= lam, ends - np.arange(lam), np.iinfo(np.int32).max)
+        want = (int(np.argmin(lengths)), int(ends[np.argmin(lengths)]))
+        if (ends > lam).all():
+            want = (0, lam)
+        alone = two_prong_select(jnp.asarray(row), float(k), EXACT_RPB)
+        assert (int(alone.start), int(alone.end)) == want
+        assert (int(batch.start[q]), int(batch.end[q])) == want
+        assert float(batch.expected_records[q]) == float(alone.expected_records)
+        if not case.startswith("random_float"):  # exact sums: the walk agrees
+            assert two_prong_faithful(row, float(k), EXACT_RPB) == want
 
 
 @settings(max_examples=10, deadline=None)

@@ -10,6 +10,7 @@ around these compiles (an entry written without a chip cannot be read back).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +104,21 @@ def test_local_round_program_compiles(one_chip):
         _spec(one_chip, (SLOTS,), jnp.float32),
     ).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_round_program_has_no_loop(one_chip):
+    """The round computes TWO-PRONG window ends by a sort-merge rank: its
+    program holds no ``while`` (a per-start binary search is a loop of
+    ⌈log2 λ⌉ gathers over ``[64, λ]``)."""
+    from repro.core.multi_query import _local_round_fn
+
+    text = _local_round_fn(RPB).lower(
+        _spec(one_chip, (SLOTS, LAM), jnp.float32),
+        _spec(one_chip, (SLOTS, LAM), jnp.bool_),
+        _spec(one_chip, (SLOTS, LAM), jnp.bool_),
+        _spec(one_chip, (SLOTS, 2), jnp.int32),
+        _spec(one_chip, (SLOTS,), jnp.int8),
+        _spec(one_chip, (SLOTS,), jnp.float32),
+    ).compile().as_text()
+    assert " sort(" in text
+    assert re.search(r"\swhile\(", text) is None
