@@ -10,7 +10,11 @@ is a file of its own, found by name:
 * ``configs/<config>.json`` — a deployment's table: generator, sizes,
   ``source``, ``reduced``, ``assumed`` and the guarantees it states;
 * ``tables/<generator>.py`` — the seeded table generator a config names;
-* ``traffic/<traffic>.json`` — a traffic mix, read by :mod:`chipbench.traffic`;
+* ``traffic/<traffic>.json`` — a traffic mix, read by :mod:`chipbench.traffic`:
+  classes of LIMIT (exemplar) requests and of online aggregates
+  (``"kind": "aggregate"``, with ``measure``, ``error_slo`` and ``k``); a mix
+  that holds an aggregate class states ``limits.agg_outside_4se`` (the
+  module's docstring gives the keys and the rule for that limit);
 * ``arrivals/<process>.py`` — an arrival process a mix names, ``make(...)``;
 * ``metrics/<metric>.py`` — a per-layer metric reader, ``read(ctx)``, with an
   optional ``probe(served)`` the harness takes at fixed moments of the window.

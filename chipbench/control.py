@@ -2,9 +2,14 @@
 guarantee the configuration states, to show that the comparison which
 decides ``correct`` fails it.
 
-Each answer becomes every valid row of the blocks the program fetched, with
-no row filter (the step a later PR that drops the host row filter would
-take).  That breaks "every returned row satisfies the predicate".
+Each LIMIT answer becomes every valid row of the blocks the program
+fetched, with no row filter (the step a later PR that drops the host row
+filter would take).  That breaks "every returned row satisfies the
+predicate".  Each aggregate answer becomes the mean of the any-k chosen arm
+alone, with that sample's standard error: the densest blocks, in order,
+until they hold ``k`` matching rows, which is the biased sample that the
+paper's §5 debiasing corrects.  Where the measure follows the layout, that
+breaks "the mean lies within 4 standard errors of the truth".
 
     python3 chipbench/control.py --workload nt71-browse --seeds 11 12 13 --seconds 10
 
@@ -40,16 +45,52 @@ def unfiltered(res, table, rpb: int):
         measures=table.measures[rows])
 
 
+def chosen_arm_only(req, arrival, scan, rpb: int):
+    """The mean of ``arrival``'s measure over the matching rows of the
+    densest blocks, taken until they hold ``arrival.k`` of them, with the
+    standard error of that sample as if it were a simple random one.  The
+    answer says it spent its whole round budget (reason ``"budget"``), so
+    the SLO check passes it by and only its distance from the truth is
+    judged."""
+    table = scan.table
+    mask = scan.mask([tuple(p) for p in arrival.predicates], arrival.op)
+    lam = -(-table.num_records // rpb)
+    pad = np.zeros(lam * rpb, bool)
+    pad[:mask.size] = mask
+    counts = pad.reshape(lam, rpb).sum(axis=1)
+    order = np.argsort(-counts, kind="stable")
+    take = order[: int(np.searchsorted(np.cumsum(counts[order]), arrival.k)) + 1]
+    rows = np.concatenate([np.arange(b * rpb, min((b + 1) * rpb, table.num_records))
+                           for b in np.sort(take)])
+    vals = table.measures[rows[mask[rows]], arrival.measure].astype(np.float64)
+    n = vals.size
+    mean = float(vals.mean()) if n else 0.0
+    var = float(vals.var(ddof=1)) / n if n > 1 else 0.0
+    est = dataclasses.replace(req.result, mean=mean, var_mean=var, num_samples=n)
+    return dataclasses.replace(req, result=est, reason="budget", rounds=req.max_rounds)
+
+
 def apply(records: list, table, rpb: int) -> list:
     """The window's records with each answer replaced by the control's."""
+    from chipbench.reference import FullScan
+    from chipbench.traffic import AGGREGATE
+
+    scan = FullScan(table)
     out = []
     for r in records:
         req = r["req"]
         r = dict(r)
         if req is not None and req.result is not None:
-            r["req"] = dataclasses.replace(req, result=unfiltered(req.result, table, rpb))
+            if r["arrival"].kind == AGGREGATE:
+                r["req"] = chosen_arm_only(req, r["arrival"], scan, rpb)
+            else:
+                r["req"] = dataclasses.replace(req, result=unfiltered(req.result, table, rpb))
         out.append(r)
     return out
+
+
+def _log(seed: int, side: str, msg: str) -> None:
+    print(f"seed {seed} {side}: {msg}", file=sys.stderr, flush=True)
 
 
 def main(argv=None) -> int:
@@ -78,9 +119,10 @@ def main(argv=None) -> int:
         out = harness.drive(sv, process, args.seconds)
         table = sv.table
         sv.server = sv.engine = sv.store = None
-        program, _ = harness.check(cell, harness.Served(table, None, None, None, None), out)
+        served = harness.Served(table, None, None, None, None)
+        program, _ = harness.check(cell, served, out, log=lambda s: _log(seed, "program", s))
         ctl_out = dataclasses.replace(out, records=apply(out.records, table, rpb))
-        control, _ = harness.check(cell, harness.Served(table, None, None, None, None), ctl_out)
+        control, _ = harness.check(cell, served, ctl_out, log=lambda s: _log(seed, "control", s))
         print("control " + json.dumps({
             "workload": cell.name, "seed": seed, "answers": len(out.records),
             "program": program, "program_correct": harness.correct(program, lim),

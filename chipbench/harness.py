@@ -32,6 +32,7 @@ MAX_GATHER_BUCKET = 4096  # tier 0 holds 1 GiB / 335,872 B = 3,196 blocks
 DRAIN_S = 60.0  # how long answers due in the window may come late
 WARM_JOINS = 16  # joiners per tick warmed; drive() holds the admission queue to this
 EXACT_CHECKS = ("missing", "bad_rows", "short")
+AGG_EXACT_CHECKS = ("agg_over_slo", "agg_exhausted_off")  # agg_outside_4se: the mix's
 
 
 # ------------------------------------------------------------ lookup by name
@@ -215,6 +216,7 @@ def warm(cell: Cell, sv: Served, seed: int, timings: dict,
         np.asarray(dd[0]), np.asarray(dm[0]), np.asarray(dv[0])
         b *= 2
     warm_joins(cell, sv, joins)
+    warm_aggregates(cell, sv)
     timings["compile"] = time.monotonic() - t
     t = time.monotonic()
     warm_s = float(cell.mix.get("warmup_s", 5.0))
@@ -233,7 +235,7 @@ def warm_joins(cell: Cell, sv: Served, most: int) -> None:
     from repro.core.multi_query import BatchQuery, DeviceWave, new_query_state
 
     shapes = sorted({(t["op"], len(t["terms"])) for c in cell.mix["classes"]
-                     for t in c["templates"]})
+                     if traffic.class_kind(c) == traffic.EXEMPLAR for t in c["templates"]})
     wave = DeviceWave(sv.engine, SLOTS)
     for op, g in shapes:
         preds = [(a, 0) for a in range(g)]
@@ -243,6 +245,23 @@ def warm_joins(cell: Cell, sv: Served, most: int) -> None:
             wave.plan_round()
             for slot in range(j):
                 wave.leave(slot)
+
+
+def warm_aggregates(cell: Cell, sv: Served) -> None:
+    """Serve one aggregate of each (op, term count, measure) of the mix's
+    aggregate classes to its answer: the any-k plan of its chosen arm, the
+    tier fills and the host fold, built before the window."""
+    todo = {}
+    for c in cell.mix["classes"]:
+        if traffic.class_kind(c) != traffic.AGGREGATE:
+            continue
+        combos = len(c["templates"]) * len(c["k"]) * len(c["measure"]) * len(c["error_slo"])
+        for a in traffic.class_requests(c, combos, cell.config):
+            todo.setdefault((a.op, len(a.predicates), a.measure), a)
+    reqs = [_submit(sv.server, a) for a in todo.values()]
+    sv.server.run_continuous(sv.engine)
+    if not all(r.done for r in reqs):
+        raise RuntimeError("the aggregate warm-up left requests unanswered")
 
 
 # --------------------------------------------------------------------- drive
@@ -255,11 +274,28 @@ class Outcome:
     unanswered: int  # due in the window, no answer DRAIN_S after the close
 
 
+def _admission(server, kind: str):
+    return (server.aggregate_admission if kind == traffic.AGGREGATE
+            else server.exemplar_admission)
+
+
 def _pools(server):
-    """(exemplar requests waiting or seated, exemplar slots seated)."""
-    ex = getattr(server, "_exemplar_loop", None)
-    busy = ex.sched.busy if ex is not None else 0
-    return busy + server.exemplar_admission.pending, busy
+    """(exemplar requests waiting or seated, requests of either pool waiting
+    or seated, slots of either pool seated)."""
+    ex, agg = getattr(server, "_exemplar_loop", None), getattr(server, "_aggregate_loop", None)
+    ex_busy = ex.sched.busy if ex is not None else 0
+    agg_busy = agg.sched.busy if agg is not None else 0
+    ex_act = ex_busy + server.exemplar_admission.pending
+    return (ex_act, ex_act + agg_busy + server.aggregate_admission.pending,
+            ex_busy + agg_busy)
+
+
+def _submit(server, a: traffic.Arrival):
+    preds = [tuple(p) for p in a.predicates]
+    if a.kind == traffic.AGGREGATE:
+        return server.submit_aggregate_request(preds, a.measure, a.k, op=a.op,
+                                               error_slo=a.error_slo)
+    return server.submit_exemplar_request(preds, a.k, a.op)
 
 
 def drive(sv: Served, process, seconds: float, hook=None,
@@ -271,17 +307,19 @@ def drive(sv: Served, process, seconds: float, hook=None,
     ``hook(rel_s)`` runs between steps inside the window, and with ``None``
     at its close (the profiler's stop, the probes).
 
-    A due request waits on the client's side while ``queue`` requests wait
-    for admission.  A tick seats all the queue holds, and the program builds
-    programs for each number of joiners, of which the warm-up builds
-    1..``queue``: after a host stall of a few seconds the queue would
-    otherwise seat more and compile inside the window.  The latency of a
-    request that waited runs from its due time all the same."""
+    A due request waits on the client's side while ``queue`` requests of
+    its kind wait for that pool's admission.  A tick seats all the queue
+    holds, and the program builds programs for each number of exemplar
+    joiners, of which the warm-up builds 1..``queue``: after a host stall of
+    a few seconds the queue would otherwise seat more and compile inside the
+    window.  The latency of a request that waited runs from its due time all
+    the same."""
     server, engine = sv.server, sv.engine
     records: list = []
     by_rid: dict = {}
     steps: list = []
-    held: deque = deque()  # due, not yet submitted
+    # due, not yet submitted, per pool
+    held = {traffic.EXEMPLAR: deque(), traffic.AGGREGATE: deque()}
     t0 = time.monotonic()
     window_open = True
 
@@ -290,25 +328,26 @@ def drive(sv: Served, process, seconds: float, hook=None,
             rec = {"arrival": a, "req": None, "due": t0 + a.due_s, "done": None,
                    "late": None}
             records.append(rec)
-            held.append(rec)
+            held[a.kind].append(rec)
         release()
 
     def release() -> None:
-        while held and server.exemplar_admission.pending < queue:
-            rec = held.popleft()
-            a = rec["arrival"]
-            req = server.submit_exemplar_request([tuple(p) for p in a.predicates], a.k, a.op)
-            rec["req"] = req
-            rec["late"] = time.monotonic() - rec["due"]
-            by_rid[req.rid] = rec
+        for kind, waiting in held.items():
+            adm = _admission(server, kind)
+            while waiting and adm.pending < queue:
+                rec = waiting.popleft()
+                req = _submit(server, rec["arrival"])
+                rec["req"] = req
+                rec["late"] = time.monotonic() - rec["due"]
+                by_rid[req.rid] = rec
 
     def step(now: float) -> None:
         release()
-        ex_act, _ = _pools(server)
+        ex_act, _, _ = _pools(server)
         out = server.step(engine, now=now)
         t1 = time.monotonic()
         steps.append((now, t1, ex_act > 0))
-        for req in out["exemplar"]:
+        for req in out["exemplar"] + out["aggregate"]:
             rec = by_rid[req.rid]
             rec["done"] = t1
             if window_open:
@@ -321,21 +360,22 @@ def drive(sv: Served, process, seconds: float, hook=None,
         if hook is not None:
             hook(now - t0)
         arrive(process.due(now - t0))
-        ex_act, busy = _pools(server)
+        _, act, busy = _pools(server)
         if busy == 0:
             # no slot seated: sleep to the next arrival or admission deadline
             wake = [t0 + seconds]
             nxt = process.next_due()
             if nxt is not None:
                 wake.append(t0 + nxt)
-            d = server.exemplar_admission.next_deadline()
-            if d is not None:
-                wake.append(d)
+            for adm in (server.exemplar_admission, server.aggregate_admission):
+                d = adm.next_deadline()
+                if d is not None:
+                    wake.append(d)
             pause = min(wake) - time.monotonic()
             if pause > 0:
                 time.sleep(min(pause, 0.05))
                 continue
-            if ex_act == 0:
+            if act == 0:
                 continue
         step(time.monotonic())
     t_end = time.monotonic()
@@ -354,28 +394,58 @@ def drive(sv: Served, process, seconds: float, hook=None,
 
 
 # --------------------------------------------------------------------- check
-def check(cell: Cell, sv: Served, out: Outcome) -> tuple[dict, list[bool]]:
+def check(cell: Cell, sv: Served, out: Outcome, log=None) -> tuple[dict, list[bool]]:
     """Compare every answer due in the window with the full scan.  Returns
-    the numbers compared and, per record, whether it failed."""
+    the numbers compared and, per record, whether it failed; ``log`` gets a
+    line on the aggregates: answers by reason, and the spread of their
+    distance from the truth in standard errors.
+
+    A sound run may hold answers beyond 4 standard errors, up to the mix's
+    limit, so such an answer is a failed record only in a run whose count
+    exceeds that limit."""
     scan = reference.FullScan(sv.table)
     rpb = int(cell.config["records_per_block"])
-    bad_rows = short = 0
+    n = {"bad_rows": 0, "short": 0, "over_slo": 0, "exhausted_off": 0, "outside_4se": 0}
+    reasons: dict = {}
+    zs = []
     failed = []
+    outside = []  # per record: beyond 4 SE and no exact check failed
     for r in out.records:
         a, req = r["arrival"], r["req"]
+        outside.append(False)
         if r["done"] is None or req is None or req.result is None:
             failed.append(True)
             continue
-        c = reference.check_exemplar(req.result, [tuple(p) for p in a.predicates], a.k,
-                                     a.op, scan, rpb)
-        bad_rows += c["bad_rows"]
-        short += c["short"]
-        failed.append(c["bad_rows"] > 0 or c["short"] > 0)
-    return {"missing": out.unanswered, "bad_rows": bad_rows, "short": short}, failed
+        preds = [tuple(p) for p in a.predicates]
+        if a.kind == traffic.AGGREGATE:
+            c = reference.check_aggregate(req, preds, a.op, a.measure, a.error_slo, scan)
+            reasons[req.reason] = reasons.get(req.reason, 0) + 1
+            zs.append(c.pop("z"))
+        else:
+            c = reference.check_exemplar(req.result, preds, a.k, a.op, scan, rpb)
+            c.pop("rows")
+        for k, v in c.items():
+            n[k] += v
+        failed.append(any(v > 0 for k, v in c.items() if k != "outside_4se"))
+        outside[-1] = not failed[-1] and c.get("outside_4se", 0) > 0
+    checks = {"missing": out.unanswered, "bad_rows": n["bad_rows"], "short": n["short"]}
+    if traffic.has_aggregates(cell.mix):
+        checks.update(agg_over_slo=n["over_slo"], agg_exhausted_off=n["exhausted_off"],
+                      agg_outside_4se=n["outside_4se"])
+        if n["outside_4se"] > limits(cell)["agg_outside_4se"]:
+            failed = [f or o for f, o in zip(failed, outside)]
+        if log is not None and zs:
+            log(f"aggregates_checked={len(zs)} by_reason={json.dumps(reasons, sort_keys=True)} "
+                f"err_over_se p50={stats.percentile(zs, 50):.3f} "
+                f"p90={stats.percentile(zs, 90):.3f} max={max(zs):.3f} "
+                f"over_2={sum(z > 2 for z in zs)} over_3={sum(z > 3 for z in zs)}")
+    return checks, failed
 
 
 def limits(cell: Cell) -> dict:
     lim = {k: 0 for k in EXACT_CHECKS}
+    if traffic.has_aggregates(cell.mix):
+        lim.update({k: 0 for k in AGG_EXACT_CHECKS})
     lim.update(cell.mix.get("limits", {}))
     return lim
 
@@ -605,15 +675,19 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices, t_start: f
               "memory_peak_bytes": mem.get("peak_bytes_in_use")}
     answers = [(r["arrival"], r["req"]) for r in out.records
                if r["done"] is not None and r["done"] <= out.t_end]
-    blocks = sum(int(req.result.blocks_fetched.size) for _, req in answers)
+    ex = [req for a, req in answers if a.kind == traffic.EXEMPLAR]
+    agg = [req for a, req in answers if a.kind == traffic.AGGREGATE]
+    blocks = sum(int(req.result.blocks_fetched.size) for req in ex)
     log(f"answered_in_window={len(answers)} blocks={blocks} modeled_io_s="
-        f"{sum(req.result.modeled_io_s for _, req in answers):.3f} "
-        "(repro.core.cost_model's HDD model: a count, not chip time)")
+        f"{sum(req.result.modeled_io_s for req in ex):.3f} "
+        "(repro.core.cost_model's HDD model: a count, not chip time)"
+        + (f" aggregates={len(agg)} agg_spent_io_s={sum(r.spent_io_s for r in agg):.3f}"
+           if agg else ""))
     spans, events = _recorded(sv.obs, out.t0, out.t_end)
     # the program's state is freed before the reference runs
     table = sv.table
     sv.server = sv.engine = sv.store = None
-    checks, failed = check(cell, Served(table, None, None, None, None), out)
+    checks, failed = check(cell, Served(table, None, None, None, None), out, log=log)
     result = {"correct": None, "attempted": len(out.records), "failed": int(sum(failed)),
               "metrics": {}, "device": device}
     e2e = end_to_end(out, failed)

@@ -1,17 +1,40 @@
 """The plain reference: a numpy full scan of the generated table, and the
 comparison of every served answer against it.
 
-Copied from ``chip_smoke.py`` (``FullScan``, ``check_exemplar``) and turned
-from assertions into counts, so a run can print each compared number beside
-its limit.  Nothing here reads blocks,
+Copied from ``chip_smoke.py`` (``FullScan``, ``check_exemplar``,
+``check_aggregate``) and turned from assertions into counts, so a run can
+print each compared number beside its limit.  Nothing here reads blocks,
 density maps, plans or anything else the program built: only the table the
 seed generated and the answers the timed path returned.
+
+An aggregate's truth is the float64 mean of its measure over every matching
+row.  Its answer is held to three numbers, for these reasons:
+
+* ``agg_over_slo`` (exact): an answer to a request with an error SLO has a
+  95% half-width, ``Z95`` standard errors, at or under that SLO, whatever
+  reason it gives, unless it read its whole round budget (``rounds`` at
+  ``max_rounds``) or is a ratio estimate of its whole design (reason
+  ``"exhausted"``, which the next check holds to the exact mean); and no
+  answer has a mean or a standard error that is not finite.
+* ``agg_exhausted_off`` (exact): a ratio-estimator answer that folded every
+  block of its design (reason ``"exhausted"``, π = 1) is the exact mean.  The
+  1e-6 relative room absorbs the float32 block sums, as ``chip_smoke`` does.
+* ``agg_outside_4se`` (statistical): any other answer's mean lies within 4
+  standard errors of the truth, plus the same 1e-6 relative room (a design
+  that read every matching block reports SE 0).  A sound estimator exceeds
+  4 SE now and then: its SE rests on as few as 8 random-arm blocks, and
+  Student's t with about 7 degrees of freedom lies beyond 4 about once in
+  200.  So this count has a limit of its own, which each mix states from
+  that sound rate (``chipbench.traffic`` gives the rule).  An answer beyond 4
+  SE is a failed record only where the run's count exceeds the limit.
 """
 from __future__ import annotations
 
 import numpy as np
 
 AND = "and"
+Z95 = 1.959963984540054  # the standard normal's 97.5% quantile
+EXACT_REL = 1e-6  # float32 block sums, relative to the mean
 
 
 class FullScan:
@@ -21,6 +44,7 @@ class FullScan:
         self.table = table
         self._eq: dict = {}
         self._groups: dict = {}
+        self._means: dict = {}
 
     def rows_match(self, idx: np.ndarray, preds, op: str) -> np.ndarray:
         """Whether each row of ``idx`` satisfies the predicate."""
@@ -34,6 +58,17 @@ class FullScan:
                 self._eq[(a, v)] = self.table.dims[:, a] == v
             ms.append(self._eq[(a, v)])
         return np.logical_and.reduce(ms) if op == AND else np.logical_or.reduce(ms)
+
+    def mean(self, preds, op: str, measure: int) -> float:
+        """The float64 mean of ``measure`` over the matching rows (0 when
+        none match)."""
+        key = (tuple(preds), op, measure)
+        if key not in self._means:
+            m = self.mask(preds, op)
+            n = int(m.sum())
+            total = np.sum(self.table.measures[:, measure], where=m, dtype=np.float64)
+            self._means[key] = float(total / n) if n else 0.0
+        return self._means[key]
 
     def count(self, preds, op: str) -> int:
         if op != AND:
@@ -74,3 +109,28 @@ def check_exemplar(res, preds, k: int, op: str, scan: FullScan, rpb: int) -> dic
     if idx.size < k and idx.size < scan.count(preds, op):
         short = 1
     return {"bad_rows": bad, "short": short, "rows": int(idx.size)}
+
+
+def check_aggregate(req, preds, op: str, measure: int, error_slo, scan: FullScan) -> dict:
+    """Counts, 0 or 1 each, of an answered aggregate (module docstring):
+    ``over_slo``, ``exhausted_off``, ``outside_4se``; and ``z``, its distance
+    from the truth beyond the rounding room, in standard errors."""
+    est = req.result
+    truth = scan.mean(preds, op, measure)
+    mean, se = float(est.mean), float(est.se_mean)
+    err = abs(mean - truth)
+    beyond = max(err - EXACT_REL * abs(truth), 0.0)  # outside the rounding room
+    out = {"over_slo": 0, "exhausted_off": 0, "outside_4se": 0,
+           "z": beyond / se if se > 0 else (0.0 if beyond == 0 else float("inf"))}
+    if not (np.isfinite(mean) and np.isfinite(se)):
+        out["over_slo"] = 1
+        return out
+    exact = req.reason == "exhausted" and req.estimator == "ratio"
+    read_all = exact or req.rounds >= req.max_rounds
+    if error_slo is not None and not read_all and Z95 * se > error_slo:
+        out["over_slo"] = 1
+    if exact:
+        out["exhausted_off"] = int(not err <= EXACT_REL * abs(truth))
+    else:
+        out["outside_4se"] = int(not err <= 4.0 * se + EXACT_REL * abs(truth))
+    return out
